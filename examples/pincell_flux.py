@@ -7,7 +7,7 @@ of BASELINE.md config 2 at laptop scale. Synthetic event-based transport
 like the real host: init → move per advance event → write.
 
 Run:  python examples/pincell_flux.py [out.vtu]
-(CPU-friendly; pass PUMI_TPU_PLATFORM=cpu to pin the platform.)
+(CPU-friendly; set JAX_PLATFORMS=cpu to pin the platform.)
 """
 from __future__ import annotations
 
@@ -19,13 +19,6 @@ sys.path.insert(
 )
 
 import numpy as np
-
-import jax
-
-from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-if not maybe_force_cpu() and os.environ.get("PUMI_TPU_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["PUMI_TPU_PLATFORM"])
 
 from pumiumtally_tpu import Material, PumiTally, SyntheticTransport, TallyConfig
 from pumiumtally_tpu.mesh.box import build_box_arrays

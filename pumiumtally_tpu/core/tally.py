@@ -30,7 +30,7 @@ def make_flux(
       On TPU a trailing dimension of 2 forces the (8,128) tile layout to
       pad the minor dim 2 → 128, a 64× HBM blowup (measured: the 1M-tet
       64-group flux allocates 32.7 GB as [ntet,64,2] vs 511 MB flat,
-      bench_out/bench_v3b_64g round 4). The walk scatters into the flat
+      round-4 builder capture, BENCHMARKS.md). The walk scatters into the flat
       stride-2 layout either way; keep device-resident accumulators flat
       and reshape host-side.
     """

@@ -51,7 +51,7 @@ production path for 1M-tet meshes (~80 MB of walk tables).
 
 Gather budget (round 3). In-loop TPU gather/scatter cost is linear in
 rows (~9-11 ns/row) with width nearly free up to ~24 f32 columns
-(scripts/microbench_costmodel2.py, microbench_record_scatter.py), so the
+(scripts/microbench_costmodel2.py; BENCHMARKS.md round 3), so the
 walk does exactly ONE gather per crossing when the mesh carries the
 packed ``geo20`` table: a 20-wide row holding face normals, plane
 offsets, AND the four per-face topology codes bitcast into the float

@@ -9,7 +9,7 @@ that live in VMEM for the whole walk, so the entire move is ONE kernel
 launch with no per-crossing HBM traffic:
 
   * GATHER → blocked one-hot matmul.  Each lane block's parent elements
-    become a ``[B, ntet]`` one-hot matrix; one ``[B, ntet] @ [ntet, 28]``
+    become a ``[B, ntet]`` one-hot matrix; one ``[B, ntet] @ [28, ntet]ᵀ``
     matmul fetches the whole decoded walk row (12 normals + 4 plane
     offsets + 4 neighbor ids + 4 material-stop bits + 4 neighbor class
     indices, every topology column stored as an exactly-representable
@@ -18,13 +18,15 @@ launch with no per-crossing HBM traffic:
     ``jnp.take`` (scripts/probe_pallas_gather.py records the lowering
     probes; the one-hot form is the one Mosaic accepts).
   * SCATTER → one-hot outer product into a tile-local accumulator.  Per
-    crossing the scored pair rides ``onehot(elem)^T @ V`` where ``V`` is
-    the ``[B, 2·n_groups]`` per-lane value matrix holding ``w·len`` at
-    column ``2g`` and ``(w·len)²`` at ``2g+1`` — a ``[ntet, B] @
-    [B, 2·n_groups]`` contraction accumulated into a VMEM-resident
-    ``[ntet, 2·n_groups]`` tile that is flushed to HBM ONCE per launch
+    crossing the scored pair rides ``Vᵀ @ onehot(elem)`` where ``Vᵀ`` is
+    the ``[2·n_groups, B]`` per-lane value matrix holding ``w·len`` at
+    row ``2g`` and ``(w·len)²`` at ``2g+1`` — a ``[2·n_groups, B] @
+    [B, ntet]`` contraction accumulated into a VMEM-resident
+    ``[2·n_groups, ntet]`` tile that is flushed to HBM ONCE per launch
     (it aliases the flux operand), replacing the per-crossing XLA
-    scatter-add entirely.
+    scatter-add entirely.  Tables and tiles keep ``ntet`` on the
+    128-wide lane axis (see ``_make_kernel``), and both contractions
+    run at ``Precision.HIGHEST`` so the MXU never rounds an operand.
 
 Bitwise parity with the XLA walk
 --------------------------------
@@ -67,7 +69,9 @@ regime).
 
 Off TPU the kernel runs in Pallas interpret mode (the parity suites run
 it on CPU); ``kernel="auto"`` only selects it on a real TPU backend
-unless ``PUMI_TPU_PALLAS_INTERPRET=1`` opts interpret mode in.
+unless ``PUMI_TPU_PALLAS_INTERPRET=1`` opts interpret mode in.  On a TPU
+it is always compiled (interpret mode there is an error) and runs
+float32 only: Mosaic has no 64-bit floats.
 """
 from __future__ import annotations
 
@@ -76,6 +80,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .geometry import exit_face
 from .walk import (
@@ -90,8 +95,11 @@ from .walk import (
 # 4 neighbor ids + 4 material-stop bits + 4 neighbor class indices.
 TABLE_COLS = 28
 DEFAULT_LANE_BLOCK = 128
-# Conservative default VMEM budget for the whole-walk-resident working
-# set (16 MB/core physical; leave headroom for Mosaic's own spills).
+# Default VMEM budget for the whole-walk-resident working set. Mosaic
+# allocates the estimate plus ~0.4 MiB (described-v5e compiles, PR 21:
+# 7.29 MiB vs 6.72 estimated at 10,368 tets / 4,096 lanes; 7.42 vs 7.02
+# at 8,192 lanes), so each launch gets twice the budget as its scoped
+# VMEM limit: 16 MiB by default, Mosaic's own default on v5e.
 DEFAULT_VMEM_MB = 8.0
 
 
@@ -171,6 +179,16 @@ def select_backend(
             f"kernel must be 'xla', 'pallas' or 'auto': {kernel!r}"
         )
     itemsize = jnp.dtype(dtype).itemsize
+    if platform is None:
+        platform = jax.default_backend()
+    if platform == "tpu" and itemsize != 4:
+        # Mosaic has no 64-bit floats: the kernel runs f32 on the chip.
+        if kernel == "auto" or not strict:
+            return "xla"
+        raise ValueError(
+            f"kernel='pallas' runs float32 on a TPU; got {jnp.dtype(dtype)}"
+            " — use kernel='xla' or 'auto'"
+        )
     need = kernel_vmem_bytes(
         ntet, n_particles, n_groups, itemsize,
         lane_block=lane_block or DEFAULT_LANE_BLOCK,
@@ -199,8 +217,6 @@ def select_backend(
             )
         return "pallas"
     # "auto"
-    if platform is None:
-        platform = jax.default_backend()
     interpret_ok = os.environ.get("PUMI_TPU_PALLAS_INTERPRET") == "1"
     if not packed or need > budget:
         return "xla"
@@ -302,9 +318,15 @@ def _pick4(vals, face):
     )
 
 
+def _col(mask):
+    """[B] bool → [B, 1] bool.  Mosaic cannot relayout an i1 vector to a
+    column; an int32 one it can."""
+    return mask.astype(jnp.int32)[:, None] != 0
+
+
 def _make_kernel(
     *,
-    n_pad: int,
+    n_blocks: int,
     lane_block: int,
     ntet: int,
     n_groups: int,
@@ -318,33 +340,44 @@ def _make_kernel(
     tolerance: float,
     tol_floor: float,
 ):
-    """Build the kernel body for one static walk configuration.  All
-    per-lane state lives as loop-carried VMEM values; the crossing loop
-    mirrors ops/walk.py's flat body op-for-op (same helpers, same
-    masking) so trajectories are bitwise identical to the XLA walk."""
-    n_blocks = n_pad // lane_block
+    """Build the kernel body for one static walk configuration.
+
+    Every VMEM array keeps its long axis on the 128-wide lane axis, so
+    none is padded out from a narrow minor dim: the walk table is
+    ``[28, ntet]``, the flux tile ``[2G, ntet]``, per-lane state
+    ``[n_blocks, B]`` (one row per lane block) and positions
+    ``[n_blocks, 3, B]``.  Each block step loads and stores its own row
+    (no value-level dynamic slice reaches Mosaic) and transposes
+    positions to the ``[B, 3]`` form the shared helpers take.  The
+    crossing loop mirrors ops/walk.py's flat body op-for-op (same
+    helpers, same masking) so trajectories are bitwise identical to the
+    XLA walk."""
     B = lane_block
     G = n_groups
+    # One-hot contractions must not round the gathered coordinates: the
+    # MXU's default f32 path runs reduced-precision passes.
+    hi = jax.lax.Precision.HIGHEST
 
     def kernel(
         tbl_ref, origin_ref, dest_ref, elem_ref, fly_ref, w_ref, g_ref,
         mat_ref, flux_ref,
         pos_out, elem_out, mat_out, done_out, pseg_out, ncross_out,
         nchase_out, nseg_out, iters_out, flux_out,
+        prev_ref, stuck_ref,
     ):
-        tbl = tbl_ref[:]
-        dest = dest_ref[:]
-        fly = fly_ref[:] != 0
-        weight = w_ref[:]
-        group = g_ref[:]
-        good_group = (group >= 0) & (group < G)
         i_lt = jax.lax.broadcasted_iota(
             jnp.int32, (B, B), 1
         ) < jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)  # j < i
         iota_bt = jax.lax.broadcasted_iota(jnp.int32, (B, ntet), 1)
-        iota_bc = jax.lax.broadcasted_iota(jnp.int32, (B, 2 * G), 1)
+        iota_cb = jax.lax.broadcasted_iota(jnp.int32, (2 * G, B), 0)
 
-        def tally_peel(acc, elemb, groupb, contrib, pending0):
+        def row(ref, b):
+            return ref[pl.ds(b, 1), :].reshape(B)
+
+        def put(ref, b, v):
+            ref[pl.ds(b, 1), :] = v.reshape(1, B)
+
+        def tally_peel(elemb, groupb, contrib, pending0):
             """Matrixized tally scatter with EXACT collision peeling:
             each pass selects the lowest still-pending lane per
             (elem, group) bin and lands the whole pass as ONE
@@ -352,8 +385,10 @@ def _make_kernel(
             order is ascending lane, the XLA scatter-add order."""
             key = elemb * G + groupb
 
-            def body(c):
-                acc, pending = c
+            # The pending mask rides the loop as int32: Mosaic cannot
+            # carry an i1 vector through a loop.
+            def body(pending_i):
+                pending = pending_i != 0
                 blocked = (
                     (key[:, None] == key[None, :])
                     & pending[None, :]
@@ -363,61 +398,62 @@ def _make_kernel(
                 csel = jnp.where(first, contrib, 0.0)
                 csq = csel * csel if score_squares else csel * 0.0
                 col = 2 * groupb
-                v = jnp.where(
-                    iota_bc == col[:, None],
-                    csel[:, None],
+                # [2G, B]: w·len at row 2g, (w·len)² at row 2g+1.
+                v_t = jnp.where(
+                    iota_cb == col[None, :],
+                    csel[None, :],
                     jnp.where(
-                        iota_bc == col[:, None] + 1,
-                        csq[:, None],
+                        iota_cb == col[None, :] + 1,
+                        csq[None, :],
                         0.0,
                     ),
                 )
                 ohe = (
-                    (elemb[:, None] == iota_bt) & first[:, None]
+                    (elemb[:, None] == iota_bt) & _col(first)
                 ).astype(dtype)
-                acc = acc + jax.lax.dot_general(
-                    ohe, v, (((0,), (0,)), ((), ())),
-                    preferred_element_type=dtype,
+                flux_out[...] += jnp.dot(
+                    v_t, ohe, precision=hi, preferred_element_type=dtype
                 )
-                return acc, pending & ~first
+                return (pending & ~first).astype(jnp.int32)
 
-            acc, _ = jax.lax.while_loop(
-                lambda c: jnp.any(c[1]), body, (acc, pending0)
+            jax.lax.while_loop(
+                lambda p: jnp.any(p != 0), body,
+                pending0.astype(jnp.int32),
             )
-            return acc
 
-        def block_step(b, carry):
+        def block_step(b, it):
             """One boundary crossing for one lane block: blocked one-hot
             gather, the shared walk arithmetic, the matrixized tally."""
-            (cur, elem, done, mat, prev, stuck, pseg, ncross, nchase,
-             nsegl, acc, it) = carry
-            s = b * B
-            curb = jax.lax.dynamic_slice(cur, (s, 0), (B, 3))
-            destb = jax.lax.dynamic_slice(dest, (s, 0), (B, 3))
-            elemb = jax.lax.dynamic_slice(elem, (s,), (B,))
-            doneb = jax.lax.dynamic_slice(done, (s,), (B,))
-            matb = jax.lax.dynamic_slice(mat, (s,), (B,))
-            prevb = jax.lax.dynamic_slice(prev, (s,), (B,))
-            stuckb = jax.lax.dynamic_slice(stuck, (s,), (B,))
-            psegb = jax.lax.dynamic_slice(pseg, (s,), (B,))
-            ncrossb = jax.lax.dynamic_slice(ncross, (s,), (B,))
-            nchaseb = jax.lax.dynamic_slice(nchase, (s,), (B,))
-            nseglb = jax.lax.dynamic_slice(nsegl, (s,), (B,))
-            flyb = jax.lax.dynamic_slice(fly, (s,), (B,))
-            weightb = jax.lax.dynamic_slice(weight, (s,), (B,))
-            groupb = jax.lax.dynamic_slice(group, (s,), (B,))
-            goodb = jax.lax.dynamic_slice(good_group, (s,), (B,))
+            curb = pos_out[b].T
+            destb = dest_ref[b].T
+            elemb = row(elem_out, b)
+            doneb = row(done_out, b) != 0
+            matb = row(mat_out, b)
+            prevb = row(prev_ref, b)
+            stuckb = row(stuck_ref, b)
+            ncrossb = row(ncross_out, b)
+            nchaseb = row(nchase_out, b)
+            nseglb = row(nseg_out, b)
+            flyb = row(fly_ref, b) != 0
+            weightb = row(w_ref, b)
+            groupb = row(g_ref, b)
+            goodb = (groupb >= 0) & (groupb < G)
 
             active = jnp.logical_not(doneb)
 
             # ONE blocked one-hot matmul fetches the whole decoded row.
             oh = (elemb[:, None] == iota_bt).astype(dtype)
-            row = jnp.dot(oh, tbl, preferred_element_type=dtype)
-            normals = row[:, :12].reshape(B, 4, 3)
-            dplane = row[:, 12:16]
-            nbrs_all = row[:, 16:20].astype(jnp.int32)
-            stop_all = row[:, 20:24].astype(jnp.int32)
-            cls_all = row[:, 24:28].astype(jnp.int32)
+            tab = jax.lax.dot_general(
+                oh, tbl_ref[...], (((1,), (1,)), ((), ())),
+                precision=hi, preferred_element_type=dtype,
+            )
+            normals = jnp.stack(
+                [tab[:, 3 * f:3 * f + 3] for f in range(4)], axis=1
+            )
+            dplane = tab[:, 12:16]
+            nbrs_all = tab[:, 16:20].astype(jnp.int32)
+            stop_all = tab[:, 20:24].astype(jnp.int32)
+            cls_all = tab[:, 24:28].astype(jnp.int32)
 
             dirv = destb - curb
             if robust:
@@ -468,14 +504,12 @@ def _make_kernel(
                 contrib = jnp.where(score, seg * weightb, 0.0).astype(
                     dtype
                 )
-                acc = tally_peel(
-                    acc, elemb, groupb, contrib, score & goodb
-                )
+                tally_peel(elemb, groupb, contrib, score & goodb)
                 nseglb = nseglb + score.astype(nseglb.dtype)
                 if ledger:
-                    psegb = psegb + jnp.where(score, seg, 0.0).astype(
-                        dtype
-                    )
+                    put(pseg_out, b, row(pseg_out, b) + jnp.where(
+                        score, seg, 0.0
+                    ).astype(dtype))
 
             domain_exit = crossed & (next_elem == -1)
             if initial:
@@ -507,7 +541,7 @@ def _make_kernel(
                     prevb,
                 )
             elemb = jnp.where(hopped, next_elem, elemb)
-            curb = jnp.where(active[:, None], xpoint, curb)
+            curb = jnp.where(_col(active), xpoint, curb)
             if robust:
                 continuing = crossed & ~newly_done
                 extra, stuckb = escalated_bump(
@@ -515,28 +549,30 @@ def _make_kernel(
                     tol_eff, curb, dnorm, dtype,
                 )
                 curb = jnp.where(
-                    continuing[:, None],
+                    _col(continuing),
                     curb + extra[:, None] * dirv,
                     curb,
                 )
             doneb = doneb | newly_done
 
-            cur = jax.lax.dynamic_update_slice(cur, curb, (s, 0))
-            elem = jax.lax.dynamic_update_slice(elem, elemb, (s,))
-            done = jax.lax.dynamic_update_slice(done, doneb, (s,))
-            mat = jax.lax.dynamic_update_slice(mat, matb, (s,))
-            prev = jax.lax.dynamic_update_slice(prev, prevb, (s,))
-            stuck = jax.lax.dynamic_update_slice(stuck, stuckb, (s,))
-            pseg = jax.lax.dynamic_update_slice(pseg, psegb, (s,))
-            ncross = jax.lax.dynamic_update_slice(ncross, ncrossb, (s,))
-            nchase = jax.lax.dynamic_update_slice(nchase, nchaseb, (s,))
-            nsegl = jax.lax.dynamic_update_slice(nsegl, nseglb, (s,))
-            return (cur, elem, done, mat, prev, stuck, pseg, ncross,
-                    nchase, nsegl, acc, it)
+            pos_out[b] = curb.T
+            put(elem_out, b, elemb)
+            put(done_out, b, doneb.astype(jnp.int32))
+            put(mat_out, b, matb)
+            put(prev_ref, b, prevb)
+            put(stuck_ref, b, stuckb)
+            put(ncross_out, b, ncrossb)
+            put(nchase_out, b, nchaseb)
+            put(nseg_out, b, nseglb)
+            return it
 
-        def crossing(carry):
-            carry = jax.lax.fori_loop(0, n_blocks, block_step, carry)
-            return carry[:-1] + (carry[-1] + 1,)
+        def all_done():
+            return jnp.all(done_out[...] != 0).astype(jnp.int32)
+
+        def crossing(c):
+            it, _ = c
+            jax.lax.fori_loop(0, n_blocks, block_step, it)
+            return it + 1, all_done()
 
         if unroll > 1:
             inner = crossing
@@ -546,43 +582,28 @@ def _make_kernel(
                     c = inner(c)
                 return c
 
+        # The loop carries "every lane done" itself: a ref read in the
+        # condition is not re-evaluated under interpret mode.
         def cond(c):
-            return jnp.logical_and(
-                c[-1] < max_crossings, jnp.logical_not(jnp.all(c[2]))
-            )
+            it, done = c
+            return jnp.logical_and(it < max_crossings, done == 0)
 
-        origin = origin_ref[:]
-        elem0 = elem_ref[:]
-        zeros_i = elem0 * 0
-        carry = (
-            origin,
-            elem0,
-            jnp.logical_not(fly),
-            mat_ref[:],
-            zeros_i - 1,          # prev: no entry face yet
-            zeros_i,              # stuck
-            weight * 0,           # pseg
-            zeros_i,              # ncross
-            zeros_i,              # nchase
-            zeros_i,              # nsegl
-            flux_ref[:].reshape(ntet, 2 * G),  # tile accumulator,
-            # seeded from the flux operand so the add chain matches the
-            # XLA per-crossing scatter association exactly
-            jnp.int32(0),
-        )
-        (cur, elem, done, mat, prev, stuck, pseg, ncross, nchase,
-         nsegl, acc, it) = jax.lax.while_loop(cond, crossing, carry)
-
-        pos_out[:] = cur
-        elem_out[:] = elem
-        mat_out[:] = mat
-        done_out[:] = done
-        pseg_out[:] = pseg
-        ncross_out[:] = ncross
-        nchase_out[:] = nchase
-        nseg_out[:] = nsegl
-        iters_out[0] = it
-        flux_out[:] = acc.reshape(-1)
+        pos_out[...] = origin_ref[...]
+        elem_out[...] = elem_ref[...]
+        done_out[...] = (fly_ref[...] == 0).astype(jnp.int32)
+        mat_out[...] = mat_ref[...]
+        zeros_i = jnp.zeros((n_blocks, B), jnp.int32)
+        prev_ref[...] = zeros_i - 1  # no entry face yet
+        stuck_ref[...] = zeros_i
+        pseg_out[...] = jnp.zeros((n_blocks, B), dtype)
+        ncross_out[...] = zeros_i
+        nchase_out[...] = zeros_i
+        nseg_out[...] = zeros_i
+        # The tile accumulator is seeded from the flux operand so the add
+        # chain matches the XLA per-crossing scatter association exactly.
+        flux_out[...] = flux_ref[...]
+        it, _ = jax.lax.while_loop(cond, crossing, (jnp.int32(0), all_done()))
+        iters_out[...] = jnp.full((1, 128), it, jnp.int32)
 
     return kernel
 
@@ -685,8 +706,14 @@ def trace_pallas_impl(
             "integrity=True needs the per-particle track-length ledger "
             "(ledger=True) for the conservation invariant"
         )
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "Pallas interpret mode is the CPU rehearsal of the kernel; on "
+            "a TPU the kernel is compiled (interpret=None or False)"
+        )
 
     in_flight = in_flight.astype(bool)
     weight = weight.astype(dtype)
@@ -699,8 +726,9 @@ def trace_pallas_impl(
     n_pad = -(-n // B) * B
     tbl = decode_walk_table(mesh)
 
+    nb = n_pad // B
     kernel = _make_kernel(
-        n_pad=n_pad,
+        n_blocks=nb,
         lane_block=B,
         ntet=ntet,
         n_groups=n_groups,
@@ -714,39 +742,60 @@ def trace_pallas_impl(
         tolerance=tolerance,
         tol_floor=tol_floor,
     )
+
+    def lanes(a, fill=0):
+        """[n] per-lane input → the kernel's [n_blocks, B] row layout."""
+        return _pad_lanes(a, n_pad, fill).reshape(nb, B)
+
+    def points(a):
+        """[n, 3] → the kernel's [n_blocks, 3, B] layout."""
+        return _pad_lanes(a, n_pad).reshape(nb, B, 3).transpose(0, 2, 1)
+
+    row_i32 = jax.ShapeDtypeStruct((nb, B), jnp.int32)
     out_shape = (
-        jax.ShapeDtypeStruct((n_pad, 3), dtype),       # position
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),     # elem
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),     # material code
-        jax.ShapeDtypeStruct((n_pad,), jnp.bool_),     # done
-        jax.ShapeDtypeStruct((n_pad,), dtype),         # pseg ledger
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),     # real crossings
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),     # chase hops
-        jax.ShapeDtypeStruct((n_pad,), jnp.int32),     # scored segments
-        jax.ShapeDtypeStruct((1,), jnp.int32),         # loop iterations
-        jax.ShapeDtypeStruct(flux_flat.shape, dtype),  # flux (aliased)
+        jax.ShapeDtypeStruct((nb, 3, B), dtype),       # position
+        row_i32,                                       # elem
+        row_i32,                                       # material code
+        row_i32,                                       # done (0/1)
+        jax.ShapeDtypeStruct((nb, B), dtype),          # pseg ledger
+        row_i32,                                       # real crossings
+        row_i32,                                       # chase hops
+        row_i32,                                       # scored segments
+        jax.ShapeDtypeStruct((1, 128), jnp.int32),     # loop iterations
+        jax.ShapeDtypeStruct((2 * n_groups, ntet), dtype),  # flux
     )
     (pos, elem_o, mat, done, pseg, ncross_l, nchase_l, nseg_l, iters,
      flux_out) = pl.pallas_call(
         kernel,
         out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((nb, B), jnp.int32),  # prev: entry-face element
+            pltpu.VMEM((nb, B), jnp.int32),  # stuck: zero-progress count
+        ],
         input_output_aliases={8: 9},  # flux operand → flux output
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * _budget_bytes()
+        ),
         interpret=interpret,
     )(
-        tbl,
-        _pad_lanes(origin, n_pad),
-        _pad_lanes(dest, n_pad),
-        _pad_lanes(elem, n_pad),
-        _pad_lanes(in_flight.astype(jnp.int32), n_pad),
-        _pad_lanes(weight, n_pad),
-        _pad_lanes(group, n_pad),
-        _pad_lanes(mat0, n_pad, fill=-2),
-        flux_flat,
+        tbl.T,
+        points(origin),
+        points(dest),
+        lanes(elem),
+        lanes(in_flight.astype(jnp.int32)),
+        lanes(weight),
+        lanes(group),
+        lanes(mat0, fill=-2),
+        flux_flat.reshape(ntet, 2 * n_groups).T,
     )
-    pos, elem_o, mat = pos[:n], elem_o[:n], mat[:n]
-    done, pseg = done[:n], pseg[:n]
-    ncross_l, nchase_l, nseg_l = ncross_l[:n], nchase_l[:n], nseg_l[:n]
-    it = iters[0]
+    flux_out = flux_out.T.reshape(-1)
+    pos = pos.transpose(0, 2, 1).reshape(n_pad, 3)[:n]
+    elem_o, mat, pseg = (a.reshape(-1)[:n] for a in (elem_o, mat, pseg))
+    done = done.reshape(-1)[:n] != 0
+    ncross_l, nchase_l, nseg_l = (
+        a.reshape(-1)[:n] for a in (ncross_l, nchase_l, nseg_l)
+    )
+    it = iters[0, 0]
 
     nseg_dtype = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
     nseg = jnp.sum(nseg_l.astype(nseg_dtype))

@@ -657,6 +657,8 @@ def make_partitioned_step(
     max_local*n_groups*2], the TPU production layout (the 3-D slab pads
     its minor dim 2 → 128 under the (8,128) tile; core.tally.make_flux) —
     sharded on its leading axis. The result keeps the caller's layout.
+    The unpacked step also carries ``step.jitted`` (the program, tables
+    first) and ``step.table_shapes``, so it can be lowered alone.
     """
     # One policy site for the backend split (ops/walk.py
     # resolve_tally_scatter: interleaved measured best on TPU, pair on
@@ -1223,6 +1225,11 @@ def make_partitioned_step(
             weight, group, pid, valid, flux, *extra,
         )
 
+    step.jitted = jitted
+    step.table_shapes = tuple(
+        jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=table_sharding)
+        for t in (*tables, *halo_tables)
+    )
     return step
 
 

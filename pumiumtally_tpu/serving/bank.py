@@ -182,6 +182,22 @@ def entry_key(family: str, args: tuple, dyn_kwargs: dict,
     return f"{family}-{h}"
 
 
+def _arg_devices(tree) -> list:
+    """The devices a dispatch of ``tree`` runs on: those its arrays are
+    committed to, else the default device. A loaded executable must
+    name them; by default JAX loads it onto every device of the host,
+    which no single-device call can feed."""
+    import jax
+
+    devices = {
+        d
+        for x in jax.tree_util.tree_leaves(tree)
+        if isinstance(x, jax.Array)
+        for d in x.devices()
+    }
+    return sorted(devices, key=lambda d: d.id) or [jax.devices()[0]]
+
+
 # --------------------------------------------------------------------- #
 # Load-time validation (the compiled half of the donation/1+1 contract)
 # --------------------------------------------------------------------- #
@@ -430,7 +446,8 @@ class ProgramBank:
 
         compiled, provenance = None, "miss"
         loaded = self._try_load(
-            fam, key, meta_path, prog_path, in_tree, out_tree, hlo_sha
+            fam, key, meta_path, prog_path, in_tree, out_tree, hlo_sha,
+            _arg_devices((args, dyn)),
         )
         if loaded is not None:
             compiled, provenance = loaded
@@ -456,11 +473,11 @@ class ProgramBank:
         return prog
 
     def _try_load(self, fam, key, meta_path, prog_path, in_tree,
-                  out_tree, hlo_sha):
-        """Load one disk entry.  Returns ``(compiled, "hit")`` on a
-        clean validated load, ``(None, "<cause>")`` when the entry
-        exists but must be rewritten (counted), or None on a plain
-        miss."""
+                  out_tree, hlo_sha, devices):
+        """Load one disk entry onto ``devices``.  Returns
+        ``(compiled, "hit")`` on a clean validated load,
+        ``(None, "<cause>")`` when the entry exists but must be
+        rewritten (counted), or None on a plain miss."""
         if not (os.path.exists(meta_path) and os.path.exists(prog_path)):
             return None
         from jax.experimental.serialize_executable import (
@@ -517,7 +534,8 @@ class ProgramBank:
                 bytes=len(payload),
             ):
                 compiled = deserialize_and_load(
-                    payload, in_tree, out_tree
+                    payload, in_tree, out_tree,
+                    execution_devices=devices,
                 )
         except Exception as e:
             self._note_rewrite(

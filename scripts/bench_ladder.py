@@ -57,11 +57,7 @@ def run_single_chip(name, cells, n_particles, n_groups, steps=5):
 
 
 def run_partitioned(n_devices=8, cells=32, n_particles=65536, steps=3):
-    import jax  # noqa: F401 — must import before the backend pin
-
-    from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-    maybe_force_cpu()
+    import jax
 
     virtual = os.environ.get("PUMI_LADDER_VIRTUAL") == "1"
     if virtual:

@@ -52,10 +52,7 @@ import numpy as np
 
 import jax
 
-from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-if not maybe_force_cpu():
-    jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_platforms", "cpu")
 
 # f64 end to end: the shrink contract compares flux ACROSS partition
 # layouts, where summation-order differences are the only allowed

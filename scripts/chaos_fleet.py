@@ -85,10 +85,7 @@ import numpy as np
 
 import jax
 
-from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-if not maybe_force_cpu():
-    jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_platforms", "cpu")
 
 from pumiumtally_tpu import TallyConfig, build_box
 from pumiumtally_tpu.obs import SLO

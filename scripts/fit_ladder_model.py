@@ -1,6 +1,6 @@
 """Fit the ladder slot model's cost coefficients to the round-4 grid.
 
-VERDICT r4 weak #5: the DP-planned dp_r250k schedule (6.93 Mseg/s)
+Round-4 review, weak item 5: the DP-planned dp_r250k schedule (6.93 Mseg/s)
 lost to the hand-built dense ladder (7.60) even though the DP is exact
 under the slot model. Either the model misprices something or its
 round-cost assumption (250k slot-equivalents per compaction round) is
@@ -34,8 +34,8 @@ from scripts.plan_ladder import (  # noqa: E402
     survivors,
 )
 
-# Measured ms/step, round-4 wave-1 hardware grid (bench_out/
-# sweep_stages.out; 1M particles, 55-cell mesh, unroll 8). The
+# Measured ms/step, round-4 wave-1 hardware grid (BENCH_GRID_r04.md,
+# sweep_stages; 1M particles, 55-cell mesh, unroll 8). The
 # tail64_96_u32 catastrophe is excluded — its 77 s/step is a different
 # regime (compile/codegen pathology), not slot-model territory.
 MEASURED_MS = {
@@ -102,9 +102,6 @@ def ladder_slots_rounds(active, n, stages, unroll=8):
 
 
 def main():
-    from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-    maybe_force_cpu()
     import jax.numpy as jnp
 
     from pumiumtally_tpu import build_box, make_flux

@@ -30,6 +30,9 @@ def timeit(name, fn, *args):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 1_048_576
     rng = np.random.default_rng(0)
     done0 = jnp.asarray(rng.random(n) < 0.7)

@@ -32,6 +32,9 @@ def timeit_donated(f, state0, *args, reps=5):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 1_048_576
     K = int(sys.argv[2]) if len(sys.argv) > 2 else 16
     bins = int(sys.argv[3]) if len(sys.argv) > 3 else 998_250 * 8

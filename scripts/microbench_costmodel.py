@@ -37,6 +37,9 @@ def timeit(name, fn, *args, iters=20):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     section = sys.argv[1] if len(sys.argv) > 1 else "all"
     ntet = int(sys.argv[2]) if len(sys.argv) > 2 else 1_000_000
     n = int(sys.argv[3]) if len(sys.argv) > 3 else 1_048_576

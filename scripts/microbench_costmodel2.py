@@ -45,6 +45,9 @@ def timeit_donated(f, state0, *args, reps=10):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     ntet = 998_250
     rng = np.random.default_rng(0)
 

@@ -41,6 +41,9 @@ ITERS = 50
 
 def main():
     global ITERS
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     import jax
     import jax.numpy as jnp
 

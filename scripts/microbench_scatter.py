@@ -41,6 +41,9 @@ def bench(name, f, args, reps=20):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 1_048_576
     ntet = int(sys.argv[2]) if len(sys.argv) > 2 else 998_250
     G = 8

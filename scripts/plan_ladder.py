@@ -168,9 +168,6 @@ def optimize_ladder(active, n, round_cost, unroll=8, grid_step=4,
 
 
 def main():
-    from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-    maybe_force_cpu()
     import jax.numpy as jnp
 
     from pumiumtally_tpu import build_box, make_flux

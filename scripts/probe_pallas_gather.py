@@ -243,6 +243,9 @@ def run_scatter(name, make_kernel, B, ntet, G, reps=20, exact=False):
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     out_path = os.environ.get("PALLAS_PROBE_OUT", "PALLAS_PROBE_r06.json")
     print(
         f"table [{T},{C}] f32, {N} lanes, device={jax.devices()[0]}, "

@@ -19,6 +19,9 @@ import numpy as np
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     import functools
 
     import jax

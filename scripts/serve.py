@@ -4,12 +4,13 @@
 Stand up the shape-bucketed scheduler over a box mesh with a
 persistent AOT program bank and serve a synthetic many-job workload:
 
-  python scripts/serve.py --demo 8                 # 8 jobs, temp bank
-  python scripts/serve.py --demo 8 --bank BANK/    # persistent bank:
+  python scripts/serve.py --demo 8                 # 8 jobs, bank at
+                                                   # .pumi_bank/serve:
                                                    # run it twice — the
                                                    # second process is
                                                    # the warm, zero-
                                                    # compile regime
+  python scripts/serve.py --demo 8 --bank BANK/    # bank elsewhere
   python scripts/serve.py --demo 8 --prom-port 9464  # live /metrics
   python scripts/serve.py --demo 8 --journal J/    # crash-safe journal
   python scripts/serve.py --demo 8 --journal J/ --resume
@@ -78,8 +79,9 @@ def main() -> int:
     ap.add_argument("--groups", type=int, default=2)
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--bank", default=None, metavar="DIR|off",
-                    help="AOT program-bank root (default: throwaway "
-                         "temp dir; 'off' = jit path)")
+                    help="AOT program-bank root (default: "
+                         ".pumi_bank/serve in the checkout; 'off' = "
+                         "jit path)")
     ap.add_argument("--classes", default="96,192",
                     help="comma list of request particle counts (each "
                          "pads to its own shape bucket)")
@@ -136,6 +138,12 @@ def main() -> int:
         run_fleet_saturation,
         run_saturation,
     )
+    from pumiumtally_tpu.utils.platform import (
+        DEFAULT_BANK_DIR,
+        use_compile_cache,
+    )
+
+    use_compile_cache()
 
     mesh = build_box(
         1.0, 1.0, 1.0, args.cells, args.cells, args.cells,
@@ -149,13 +157,11 @@ def main() -> int:
     # The bank rides as a PATH: the scheduler then constructs it on
     # its own registry, so the pumi_aot_* counters land on the same
     # Prometheus endpoint as the job metrics.
-    tmp_bank = tmp_ck = None
+    tmp_ck = None
     if args.bank == "off":
         bank = None
-    elif args.bank:
-        bank = args.bank
     else:
-        tmp_bank = bank = tempfile.mkdtemp(prefix="pumi_bank_")
+        bank = args.bank or os.path.join(DEFAULT_BANK_DIR, "serve")
     ck_dir = None
     if (args.preempt_after is not None and args.journal is None
             and args.fleet is None):
@@ -199,7 +205,7 @@ def main() -> int:
                 resume=args.resume,
             )
     finally:
-        for d in (tmp_bank, tmp_ck, tmp_fleet):
+        for d in (tmp_ck, tmp_fleet):
             if d is not None:
                 shutil.rmtree(d, ignore_errors=True)
     out.pop("results")  # raw flux arrays — not JSON material

@@ -44,10 +44,7 @@ if "--chaos" in sys.argv and (
 
 import jax
 
-from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-if not maybe_force_cpu():
-    jax.config.update("jax_platforms", "cpu")  # CPU soak by default
+jax.config.update("jax_platforms", "cpu")  # a CPU soak
 import jax.numpy as jnp
 from pumiumtally_tpu import make_flux
 from pumiumtally_tpu.mesh.box import build_box_arrays

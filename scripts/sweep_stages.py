@@ -20,11 +20,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():
-    import jax  # noqa: F401 — must import before the backend pin
+    from pumiumtally_tpu.utils.platform import require_tpu
 
-    from pumiumtally_tpu.utils.platform import maybe_force_cpu
-
-    maybe_force_cpu()
+    require_tpu()  # chip timings only: no silent CPU fallback
+    import jax
     import jax.numpy as jnp
 
     from pumiumtally_tpu import build_box, make_flux

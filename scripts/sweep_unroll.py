@@ -17,6 +17,9 @@ import numpy as np
 
 
 def main():
+    from pumiumtally_tpu.utils.platform import require_tpu
+
+    require_tpu()  # chip timings only: no silent CPU fallback
     import jax
     import jax.numpy as jnp
 

@@ -25,7 +25,7 @@ from pumiumtally_tpu.utils.ladder import (
 )
 
 M = 1048576
-# Round-4 hardware grid (bench_out/sweep_stages.out): name -> (schedule,
+# Round-4 hardware grid (BENCH_GRID_r04.md, sweep_stages): name -> (schedule,
 # measured ms/step). The simulator must reproduce the measured ordering
 # of the three structurally distinct families.
 GRID = {
