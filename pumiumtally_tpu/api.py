@@ -56,7 +56,7 @@ from .obs import (
 from .ops import staging
 from .ops.walk import trace, trace_packed
 from .utils.config import TallyConfig
-from .utils.profiling import annotate
+from .utils.profiling import CallSpans, annotate
 from .utils.timing import TallyTimes, phase_timer
 
 
@@ -582,16 +582,38 @@ class PumiTally:
             return stats_d["truncated"]
         return int(np.sum(~np.asarray(result.done)))
 
-    def _warn_if_truncated(self, n_lost: int) -> None:
+    def _warn_if_truncated(self, n_lost: int, lanes=None) -> None:
         if n_lost:
+            named = ""
+            if lanes:
+                named = " Truncated lanes (particle id, element, " \
+                    "position): " + "; ".join(
+                        f"{ln['pid']}, {ln['elem']}, {tuple(ln['pos'])}"
+                        for ln in lanes
+                    ) + "."
             warnings.warn(
                 f"{n_lost} particle walk(s) truncated at max_crossings="
                 f"{self._max_crossings}; tallies for them are incomplete. "
                 "Raise TallyConfig.max_crossings or set "
-                "truncation_retries for bounded re-walk escalation.",
+                f"truncation_retries for bounded re-walk escalation.{named}",
                 RuntimeWarning,
-                stacklevel=3,
+                # caller → public call → its body → here
+                stacklevel=4,
             )
+
+    def _truncated_lanes(self, done_h, pos_h, elem_dev, k: int = 8):
+        """Up to ``k`` truncated lanes, named by particle id, parent
+        element and position, from a readback's host ``done`` and
+        position columns (host pid order). Cold: the one extra transfer
+        (the named lanes' elements) happens only when a lane truncated."""
+        pids = np.flatnonzero(~done_h)[:k]
+        slots = pids if self._perm is None else self._inv_perm()[pids]
+        elems = np.asarray(jax.device_get(elem_dev[jnp.asarray(slots)]))
+        return [
+            {"pid": int(p), "elem": int(e),
+             "pos": [float(x) for x in pos_h[p]]}
+            for p, e in zip(pids, elems)
+        ]
 
     # ------------------------------------------------------------------ #
     def _quarantine(self, dest3, weights, move):
@@ -627,8 +649,9 @@ class PumiTally:
         truncation count without a device scan) and its ``io`` accounting
         dict; a re-walk then refreshes the caller's host views through
         ONE cold-path coalesced readback.  Returns ``(result, stats_d,
-        n_lost, parts)`` where ``parts`` is the refreshed
-        split_trace_readback tuple (packed, after a re-walk) or None."""
+        n_lost, parts, rewalks)`` where ``parts`` is the refreshed
+        split_trace_readback tuple (packed, after a re-walk) or None and
+        ``rewalks`` counts the re-walk dispatches."""
         if stats_d is not None:
             n_tr = stats_d["truncated"]
         elif done_h is not None:
@@ -636,15 +659,20 @@ class PumiTally:
         else:
             n_tr = self._n_truncated(result, None)
         if not n_tr:
-            return result, stats_d, 0, None
-        n_lost, n_retried, parts = n_tr, 0, None
+            return result, stats_d, 0, None, 0
+        n_lost, n_retried, parts, rewalks = n_tr, 0, None, 0
         if self.config.truncation_retries > 0:
             from .ops.walk import rewalk_truncated
+
+            def counted(*args, **kwargs):
+                nonlocal rewalks
+                rewalks += 1
+                return self._trace(*args, **kwargs)
 
             result, n_retried, n_lost = rewalk_truncated(
                 self.mesh, result, dest, weight, group,
                 retries=self.config.truncation_retries,
-                trace_fn=self._trace, **tkw,
+                trace_fn=counted, **tkw,
             )
             if io is not None:
                 host_rb = jax.device_get(
@@ -670,7 +698,7 @@ class PumiTally:
                 stats_d = self._read_stats(result)
         if n_retried or n_lost:
             self._telemetry.record_rewalk(move, n_retried, n_lost)
-        return result, stats_d, n_lost, parts
+        return result, stats_d, n_lost, parts, rewalks
 
     # ------------------------------------------------------------------ #
     def initialize_particle_location(
@@ -681,6 +709,11 @@ class PumiTally:
         discover parent elements; nothing is tallied
         (search_initial_elements + search_and_rebuild(initial=True),
         cpp:360-385, 741-746)."""
+        with CallSpans("PumiTally.initialize_particle_location") as spans:
+            spans.open("stage")
+            self._initialize(init_particle_positions, size, spans)
+
+    def _initialize(self, init_particle_positions, size, spans) -> None:
         pos = np.ascontiguousarray(
             init_particle_positions, dtype=np.float64
         ).reshape(-1)
@@ -697,7 +730,8 @@ class PumiTally:
             fly_h &= ~qmask  # masked lanes stay at the seed
         self._check_finite("init_particle_positions", pos3)
         t_before = self.tally_times.initialization_time
-        with annotate("PumiTally.initialize_particle_location"), phase_timer(
+        lost_lanes = None
+        with phase_timer(
             self.tally_times, "initialization_time", True
         ) as timer:
             s = self.state
@@ -738,19 +772,23 @@ class PumiTally:
                 flux_in, perm_in = self.flux, self._perm_dev
 
                 def _step():
-                    out = self._trace(
-                        self.mesh, s.origin, s.elem, s.material_id,
-                        rec_dev, flux_in, perm_in,
-                        weight=s.weight, group=s.group, _packed=True,
-                        **tkw,
-                    )
-                    return out, jax.device_get(out[1])
+                    with annotate("PumiTally.dispatch"):
+                        out = self._trace(
+                            self.mesh, s.origin, s.elem, s.material_id,
+                            rec_dev, flux_in, perm_in,
+                            weight=s.weight, group=s.group, _packed=True,
+                            **tkw,
+                        )
+                    with annotate("PumiTally.readback"):
+                        return out, jax.device_get(out[1])
 
+                spans.close()
                 out, host_rb = self._dispatch(_step, 0)
+                spans.open("fold")
                 result, readback, dest, _fly, _w, _g = out
                 io["d2h_bytes"] += int(host_rb.nbytes)
                 io["d2h_transfers"] += 1
-                _pos, _mats, done_h, tail, integ, _conv = (
+                pos_h, _mats, done_h, tail, integ, _conv = (
                     staging.split_trace_readback(
                         host_rb, n, self.config.dtype,
                         integrity=self._integrity != "off",
@@ -759,12 +797,18 @@ class PumiTally:
                 stats_d = (
                     stats_to_dict(tail) if self.config.walk_stats else None
                 )
-                result, stats_d, n_lost, _parts = self._escalate_truncated(
-                    result, dest, s.weight, s.group, stats_d, tkw, 0,
-                    done_h=done_h, io=io,
+                result, stats_d, n_lost, parts, rewalks = (
+                    self._escalate_truncated(
+                        result, dest, s.weight, s.group, stats_d, tkw, 0,
+                        done_h=done_h, io=io,
+                    )
                 )
-                if _parts is not None:
-                    integ = _parts[4]
+                if parts is not None:
+                    pos_h, _mats, done_h, _tail, integ, _ = parts
+                if n_lost:
+                    lost_lanes = self._truncated_lanes(
+                        done_h, pos_h, result.elem
+                    )
             else:
                 dest_h = self._gather_in(pos3)
                 dest = jnp.asarray(dest_h, dtype=self.config.dtype)
@@ -777,26 +821,32 @@ class PumiTally:
                 flux_in = self.flux  # bound pre-closure (see above)
 
                 def _step():
-                    r = self._trace(
-                        self.mesh,
-                        s.origin,
-                        dest,
-                        s.elem,
-                        fly_dev,
-                        s.weight,
-                        s.group,
-                        s.material_id,
-                        flux_in,
-                        **tkw,
-                    )
-                    return r, self._read_stats(r)
+                    with annotate("PumiTally.dispatch"):
+                        r = self._trace(
+                            self.mesh,
+                            s.origin,
+                            dest,
+                            s.elem,
+                            fly_dev,
+                            s.weight,
+                            s.group,
+                            s.material_id,
+                            flux_in,
+                            **tkw,
+                        )
+                    with annotate("PumiTally.readback"):
+                        return r, self._read_stats(r)
 
+                spans.close()
                 result, stats_d = self._dispatch(_step, 0)
+                spans.open("fold")
                 if result.stats is not None:
                     io["d2h_bytes"] += int(result.stats.nbytes)
                     io["d2h_transfers"] += 1
-                result, stats_d, n_lost, _ = self._escalate_truncated(
-                    result, dest, s.weight, s.group, stats_d, tkw, 0
+                result, stats_d, n_lost, _, rewalks = (
+                    self._escalate_truncated(
+                        result, dest, s.weight, s.group, stats_d, tkw, 0
+                    )
                 )
                 integ = (
                     np.asarray(result.integrity, np.float64)
@@ -812,7 +862,7 @@ class PumiTally:
             )
             self._store_xpoints(result)
             self._initialized = True
-            self._warn_if_truncated(n_lost)
+            self._warn_if_truncated(n_lost, lost_lanes)
             # Integrity surface for the location search: flux must stay
             # untouched/finite and the lane accounting must close (the
             # conservation triple is identically zero here — nothing is
@@ -823,6 +873,9 @@ class PumiTally:
             )
             if self.config.measure_time:
                 timer.sync(self.state)
+        io.update(lanes=int(fly_h.sum()), dispatches=1 + rewalks)
+        if lost_lanes:
+            io["truncated_lanes"] = lost_lanes
         self._telemetry.record_walk(
             "initial_search",
             0,
@@ -870,6 +923,17 @@ class PumiTally:
         """Advance every in-flight particle to its destination, tally flux,
         and write the (possibly boundary-clipped) final positions and
         material ids back into the caller's arrays (cpp:221-264)."""
+        with CallSpans("PumiTally.move_to_next_location") as spans:
+            spans.open("stage")
+            self._move(
+                particle_destinations, flying, weights, groups,
+                material_ids, size, spans,
+            )
+
+    def _move(
+        self, particle_destinations, flying, weights, groups, material_ids,
+        size, spans,
+    ) -> None:
         assert self._initialized, (
             "initialize_particle_location must run before moves"
         )
@@ -901,7 +965,8 @@ class PumiTally:
         self._check_finite("weights", weights_h)
 
         t_before = self.tally_times.total_time_to_tally
-        with annotate("PumiTally.move_to_next_location"), phase_timer(
+        lost_lanes = None
+        with phase_timer(
             self.tally_times, "total_time_to_tally", True
         ) as timer:
             s = self.state
@@ -969,11 +1034,12 @@ class PumiTally:
                 deadline = self.config.move_deadline_s is not None
 
                 def _step():
-                    out = self._trace(
-                        self.mesh, s.origin, s.elem, s.material_id,
-                        rec_dev, flux_in,
-                        perm_in, _packed=True, **tkw, **ckw,
-                    )
+                    with annotate("PumiTally.dispatch"):
+                        out = self._trace(
+                            self.mesh, s.origin, s.elem, s.material_id,
+                            rec_dev, flux_in,
+                            perm_in, _packed=True, **tkw, **ckw,
+                        )
                     if self._io == "overlap" and not deadline:
                         # Deferred bookkeeping of the PREVIOUS move
                         # runs here, overlapping the device walk of
@@ -981,10 +1047,14 @@ class PumiTally:
                         # must stay mutation-free (an abandoned worker
                         # must never touch _pending_folds/telemetry),
                         # so the drain moves after the dispatch.
-                        self._drain_pending()
-                    return out, jax.device_get(out[1])
+                        with annotate("PumiTally.fold"):
+                            self._drain_pending()
+                    with annotate("PumiTally.readback"):
+                        return out, jax.device_get(out[1])
 
+                spans.close()
                 out, host_rb = self._dispatch(_step, self.iter_count + 1)
+                spans.open("fold")
                 if self._io == "overlap" and deadline:
                     self._drain_pending()
                 result, readback, dest, in_flight, weight, group = out
@@ -1004,15 +1074,21 @@ class PumiTally:
                 stats_d = (
                     stats_to_dict(tail) if cfg.walk_stats else None
                 )
-                result, stats_d, n_lost, parts = self._escalate_truncated(
-                    result, dest, weight, group, stats_d, tkw,
-                    self.iter_count + 1, done_h=done_h, io=io,
+                result, stats_d, n_lost, parts, rewalks = (
+                    self._escalate_truncated(
+                        result, dest, weight, group, stats_d, tkw,
+                        self.iter_count + 1, done_h=done_h, io=io,
+                    )
                 )
                 if parts is not None:
                     # The refreshed cold readback has no convergence
                     # tail; the main dispatch's summary stands (the
                     # re-walk's scores enter the NEXT batch's delta).
                     final_pos, final_mats, done_h, tail, integ, _ = parts
+                if n_lost:
+                    lost_lanes = self._truncated_lanes(
+                        done_h, final_pos, result.elem
+                    )
             else:
                 dest = jnp.asarray(
                     self._gather_in(dest3_h), dtype=cfg.dtype
@@ -1035,24 +1111,28 @@ class PumiTally:
                 flux_in = self.flux  # bound pre-closure (see above)
 
                 def _step():
-                    r = self._trace(
-                        self.mesh,
-                        s.origin,
-                        dest,
-                        s.elem,
-                        in_flight,
-                        weight,
-                        group,
-                        s.material_id,
-                        flux_in,
-                        **tkw,
-                        **ckw,
-                    )
-                    return r, self._read_stats(r)
+                    with annotate("PumiTally.dispatch"):
+                        r = self._trace(
+                            self.mesh,
+                            s.origin,
+                            dest,
+                            s.elem,
+                            in_flight,
+                            weight,
+                            group,
+                            s.material_id,
+                            flux_in,
+                            **tkw,
+                            **ckw,
+                        )
+                    with annotate("PumiTally.readback"):
+                        return r, self._read_stats(r)
 
+                spans.close()
                 result, stats_d = self._dispatch(
                     _step, self.iter_count + 1
                 )
+                spans.open("fold")
                 conv_new = result.conv_state  # main dispatch (see above)
                 conv_h = None
                 if result.convergence is not None:
@@ -1064,9 +1144,11 @@ class PumiTally:
                 if result.stats is not None:
                     io["d2h_bytes"] += int(result.stats.nbytes)
                     io["d2h_transfers"] += 1
-                result, stats_d, n_lost, _ = self._escalate_truncated(
-                    result, dest, weight, group, stats_d, tkw,
-                    self.iter_count + 1,
+                result, stats_d, n_lost, _, rewalks = (
+                    self._escalate_truncated(
+                        result, dest, weight, group, stats_d, tkw,
+                        self.iter_count + 1,
+                    )
                 )
                 integ = (
                     np.asarray(result.integrity, np.float64)
@@ -1137,7 +1219,7 @@ class PumiTally:
             # The truncation warning is a user-facing contract and stays
             # in-call in every pipeline mode; only the telemetry fold is
             # deferred under "overlap".
-            self._warn_if_truncated(n_lost)
+            self._warn_if_truncated(n_lost, lost_lanes)
 
             # Self-verification (integrity/): evaluate the fused
             # invariant vector + shadow-audit sample and escalate per
@@ -1162,6 +1244,9 @@ class PumiTally:
                 timer.sync(self.state)
         self.tally_times.n_moves += 1
         seconds = self.tally_times.total_time_to_tally - t_before
+        io.update(lanes=int(fly_h.sum()), dispatches=1 + rewalks)
+        if lost_lanes:
+            io["truncated_lanes"] = lost_lanes
         if self._io == "overlap":
             # Defer the telemetry fold so this move's bookkeeping
             # overlaps the NEXT move's device walk; flushed by
@@ -1307,6 +1392,14 @@ class PumiTally:
         way). Returns the accumulated physics counters
         (ops/source.py MEGA_PHYS_FIELDS + ``moves`` + ``segments``).
         """
+        with CallSpans("PumiTally.run_source_moves") as spans:
+            spans.open("stage")
+            return self._source_moves(
+                n_moves, source, weights, groups, alive, spans
+            )
+
+    def _source_moves(self, n_moves, source, weights, groups, alive,
+                      spans) -> dict:
         assert self._initialized, (
             "initialize_particle_location must run before source moves"
         )
@@ -1344,15 +1437,16 @@ class PumiTally:
         }
         stage = dict(h2d_bytes=0, h2d_transfers=0)
         self._stage_source_lanes(weights, groups, alive, stage)
+        spans.close()
         done_moves = 0
         while done_moves < n_moves:
             k = min(K, n_moves - done_moves)
             t_before = self.tally_times.total_time_to_tally
-            with annotate("PumiTally.run_source_moves"), phase_timer(
+            with phase_timer(
                 self.tally_times, "total_time_to_tally", True
             ) as timer:
                 s = self.state
-                move0 = jax.device_put(np.int32(self.iter_count))
+                move_no = np.int32(self.iter_count)
                 io = dict(
                     h2d_bytes=4 + stage.pop("h2d_bytes", 0),
                     h2d_transfers=1 + stage.pop("h2d_transfers", 0),
@@ -1363,21 +1457,26 @@ class PumiTally:
                 prev_in = self._prev_even
 
                 def _go():
-                    margs = (
-                        self.mesh, s.origin, s.elem, s.material_id,
-                        s.weight, s.group, s.in_flight, s.particle_id,
-                        flux_in, move0, rng_key, sig_dev, ab_dev,
-                        prev_in, conv_in,
-                    )
-                    mkw = dict(n_moves=k, **statics)
-                    if self._bank is not None:
-                        out = self._bank.dispatch(
-                            "megastep", margs, mkw,
-                            shape_key=self.shape_key,
+                    # The chunk's one H2D (its move counter) rides the
+                    # dispatch span: the stage span is the call's.
+                    with annotate("PumiTally.dispatch"):
+                        move0 = jax.device_put(move_no)
+                        margs = (
+                            self.mesh, s.origin, s.elem, s.material_id,
+                            s.weight, s.group, s.in_flight, s.particle_id,
+                            flux_in, move0, rng_key, sig_dev, ab_dev,
+                            prev_in, conv_in,
                         )
-                    else:
-                        out = megastep_fn(*margs, **mkw)
-                    return out, jax.device_get(out.readback)
+                        mkw = dict(n_moves=k, **statics)
+                        if self._bank is not None:
+                            out = self._bank.dispatch(
+                                "megastep", margs, mkw,
+                                shape_key=self.shape_key,
+                            )
+                        else:
+                            out = megastep_fn(*margs, **mkw)
+                    with annotate("PumiTally.readback"):
+                        return out, jax.device_get(out.readback)
 
                 # Amnesty key includes k: each distinct chunk length
                 # compiles its own program (n_moves is static), and the
@@ -1386,6 +1485,7 @@ class PumiTally:
                 out, host_rb = self._dispatch(
                     _go, self.iter_count + 1, kind=f"megastep:{k}"
                 )
+                spans.open("fold")
                 self.flux = out.flux
                 if self._monitor is not None:
                     self._conv = out.conv_state
@@ -1438,11 +1538,14 @@ class PumiTally:
                     timer.sync(self.state)
             self.tally_times.n_moves += k
             seconds = self.tally_times.total_time_to_tally - t_before
+            # Every lane rides each fused move (dead lanes parked), so
+            # the walk's width is the batch.
             self._telemetry.record_walk(
                 "megastep", self.iter_count, stats_d,
                 seconds=seconds, synced=cfg.measure_time, moves=k,
                 collisions=p["collisions"], escaped=p["escaped"],
-                rouletted=p["rouletted"], alive=p["alive"], **io,
+                rouletted=p["rouletted"], alive=p["alive"],
+                lanes=self.num_particles, dispatches=1, **io,
             )
             if self._monitor is not None and conv_h is not None:
                 self._monitor.update(
@@ -1456,6 +1559,7 @@ class PumiTally:
             totals["absorbed_weight"] += p["absorbed_weight"]
             totals["alive"] = p["alive"]
             done_moves += k
+            spans.close()
             if p["alive"] == 0:
                 break
         return totals

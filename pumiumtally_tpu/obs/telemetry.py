@@ -6,6 +6,8 @@ on metric names or record schemas. The facade calls:
   * ``record_walk(kind, move, stats, seconds=..., **extra)`` once per
     trace (initial search or move) with the host view of the on-device
     stats vector (obs.walk_stats.stats_to_dict / reduce_chip_stats);
+    the single-chip facade's extras name the ``lanes`` the call walked
+    and the ``dispatches`` (compiled walk-program launches) it made;
   * ``record_memory(phase)`` at phase boundaries (construction, VTK
     write) to capture per-device HBM peaks;
   * ``snapshot(times=...)`` from ``tally.telemetry()``.
@@ -15,7 +17,8 @@ tallies don't interleave):
   pumi_moves_total, pumi_segments_total, pumi_crossings_total,
   pumi_truncated_walks_total, pumi_chase_hops_total,
   pumi_migration_rounds_total, pumi_compaction_occupancy,
-  pumi_move_seconds, pumi_device_peak_bytes{device=...}
+  pumi_move_seconds, pumi_device_peak_bytes{device=...},
+  pumi_dispatches_total
 
 Resilience families (fed by the quarantine / truncation-escalation
 paths, resilience/):
@@ -67,6 +70,11 @@ class TallyTelemetry:
         self._occ = r.gauge(
             "pumi_compaction_occupancy",
             "mean post-compaction active occupancy of the last trace",
+        )
+        self._dispatches = r.counter(
+            "pumi_dispatches_total",
+            "compiled walk-program launches (re-walks and megastep "
+            "chunks each count one)",
         )
         self._move_s = r.histogram(
             "pumi_move_seconds", "wall-clock seconds per facade move"
@@ -170,6 +178,8 @@ class TallyTelemetry:
                 self._occ.set(stats["occupancy"])
         if "rounds" in extra:
             self._rounds.inc(int(extra["rounds"]))
+        if "dispatches" in extra:
+            self._dispatches.inc(int(extra["dispatches"]))
         # I/O accounting riding the same per-trace record (the facade
         # passes what it actually staged — packed: one record each way;
         # legacy: one entry per staged array).
@@ -275,6 +285,7 @@ class TallyTelemetry:
                 "truncated": self._truncated.value(),
                 "chase_hops": self._chase.value(),
                 "migration_rounds": self._rounds.value(),
+                "dispatches": self._dispatches.value(),
                 "quarantined": quarantined,
                 "rewalked": self._rewalked.value(),
                 "lost": self._lost.value(),

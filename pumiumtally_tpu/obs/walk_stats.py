@@ -46,11 +46,30 @@ WALK_STATS_FIELDS = (
     # While-loop body iterations executed (TraceResult.n_crossings; for
     # the partitioned walk: phase-1 iters + all follow-up round iters).
     "loop_iters",
+    # Lane-slots executed: one lane swept through one body iteration —
+    # n x the full-width phase's iterations plus, for every compaction
+    # round, its width S x that round's iterations (per chip for the
+    # partitioned walk; the Pallas kernel sweeps its padded width every
+    # iteration). Computed after each loop from the scalar iteration
+    # counts, never per lane. CARRIER: a float32 whose bits ride the
+    # integer vector (``LANE_SLOTS``), since the count passes int32
+    # within one megastep (2^20 lanes x 1,000 iterations x 12 moves is
+    # about 1.3e10); exact below 2^24, and above it to one part in 2^24.
+    "lane_slots",
 )
 
 WALK_STATS_LEN = len(WALK_STATS_FIELDS)
 
 IDX = {name: i for i, name in enumerate(WALK_STATS_FIELDS)}
+
+# The one field carried as float32 bits (see "lane_slots" above).
+LANE_SLOTS = IDX["lane_slots"]
+
+
+def lane_slots_value(word) -> int:
+    """Host decode of the ``lane_slots`` word: the low 32 bits of the
+    (possibly sign-widened) integer are a float32."""
+    return int(np.asarray(word, np.int64).astype(np.int32).view(np.float32))
 
 
 def _derived(d: dict) -> dict:
@@ -69,7 +88,9 @@ def stats_to_dict(vec) -> dict:
         raise ValueError(
             f"expected a [{WALK_STATS_LEN}] stats vector, got {v.shape}"
         )
-    return _derived({f: int(v[i]) for i, f in enumerate(WALK_STATS_FIELDS)})
+    d = {f: int(v[i]) for i, f in enumerate(WALK_STATS_FIELDS)}
+    d["lane_slots"] = lane_slots_value(v[LANE_SLOTS])
+    return _derived(d)
 
 
 def reduce_chip_stats(mat) -> dict:
@@ -82,4 +103,5 @@ def reduce_chip_stats(mat) -> dict:
         )
     d = {f: int(m[:, i].sum()) for i, f in enumerate(WALK_STATS_FIELDS)}
     d["max_crossings"] = int(m[:, IDX["max_crossings"]].max(initial=0))
+    d["lane_slots"] = sum(lane_slots_value(w) for w in m[:, LANE_SLOTS])
     return _derived(d)

@@ -252,12 +252,13 @@ def normalize_compact_stages(
     return compact_stages
 
 
-def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it):
-    """Reduce the per-lane telemetry counters to the [8] per-move stats
+def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
+                      slots):
+    """Reduce the per-lane telemetry counters to the [9] per-move stats
     vector (obs/walk_stats.py WALK_STATS_FIELDS order — drift breaks
     tests/test_obs.py). ONE definition shared by the XLA walk body and
     the Pallas kernel path (ops/walk_pallas.py), so the schema cannot
-    fork between backends."""
+    fork between backends. ``slots`` is the float32 lane-slot count."""
     sd_t = nseg.dtype
     return jnp.stack([
         jnp.sum(ncross_l).astype(sd_t),
@@ -268,7 +269,33 @@ def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it):
         occ1.astype(sd_t),
         nseg,
         it.astype(sd_t),
+        lane_slots_word(slots, sd_t),
     ])
+
+
+def lane_slots_word(slots, dtype):
+    """A float32 lane-slot count as its bits in an integer word of
+    ``dtype`` (obs/walk_stats.py: the field's carrier)."""
+    return jax.lax.bitcast_convert_type(
+        slots.astype(jnp.float32), jnp.int32
+    ).astype(dtype)
+
+
+def _lane_slots_f32(word):
+    return jax.lax.bitcast_convert_type(word.astype(jnp.int32), jnp.float32)
+
+
+def add_walk_stats(a, b):
+    """``a + b`` for stats vectors (or [..., LEN] per-chip matrices):
+    the integer fields add, ``lane_slots`` adds as the float32 its bits
+    hold. Callers override the fields that do not sum."""
+    from ..obs.walk_stats import LANE_SLOTS
+
+    slots = _lane_slots_f32(a[..., LANE_SLOTS]) + _lane_slots_f32(
+        b[..., LANE_SLOTS]
+    )
+    out = a + b
+    return out.at[..., LANE_SLOTS].set(lane_slots_word(slots, out.dtype))
 
 
 def integrity_vector(
@@ -382,10 +409,11 @@ class TraceResult(NamedTuple):
       |position − origin| to fp accumulation (asserted under
       debug_checks, the reference's cpp:618-629 consistency print);
       zeros on initial-search traces (nothing is scored).
-    stats: [8] per-move telemetry vector in the field order of
+    stats: [9] per-move telemetry vector in the field order of
       obs/walk_stats.py WALK_STATS_FIELDS — (real crossings, max real
       crossings per particle, chase hops, truncated walks, compaction
-      occupancy numerator/denominator, segments, loop iterations) —
+      occupancy numerator/denominator, segments, loop iterations,
+      lane-slots as float32 bits) —
       computed inside the jitted program so ONE scalar-vector readback
       per move carries the whole flight-recorder record (the facade's
       old per-move host scan of ``done`` goes away). None with
@@ -574,7 +602,7 @@ def trace_impl(
         per-lane counters (real crossings, chase hops) updated
         elementwise per crossing — the same cost class as the ledger —
         plus a [2] occupancy accumulator bumped once per compaction
-        round, reduced to one [8] vector at the end. No extra
+        round, reduced to one [9] vector at the end. No extra
         dispatches, no extra readbacks (the caller fetches the vector
         INSTEAD of scanning ``done`` host-side). False restores the
         exact pre-telemetry carry for A/B cost attribution.
@@ -804,257 +832,261 @@ def trace_impl(
                 xp, kx = lanes[-2], lanes[-1]
             active = jnp.logical_not(done)
 
-            if packed:
-                if gathers == "merged":
-                    # ONE gather: normals + offsets + bitcast topo codes.
-                    geo = mesh.geo20[elem]  # [m, 20]
-                    geo_g, codes_f = geo[:, :16], geo[:, 16:20]
+            with jax.named_scope("walk.gather"):
+                if packed:
+                    if gathers == "merged":
+                        # ONE gather: normals + offsets + bitcast topo codes.
+                        geo = mesh.geo20[elem]  # [m, 20]
+                        geo_g, codes_f = geo[:, :16], geo[:, 16:20]
+                    else:
+                        # Two narrower gathers from slices of the same table
+                        # (round-2 pattern): 16-wide geometry + 4-wide topo.
+                        geo_g = mesh.geo20[:, :16][elem]
+                        codes_f = mesh.geo20[:, 16:20][elem]
+                    normals = geo_g[:, :12].reshape(-1, 4, 3)
+                    dplane = geo_g[:, 12:16]
+                    codes = jax.lax.bitcast_convert_type(
+                        codes_f, code_int
+                    ).astype(jnp.int32)  # [m, 4]
+                    nbrs_all = (codes & 0xFFFFFF) - 1
                 else:
-                    # Two narrower gathers from slices of the same table
-                    # (round-2 pattern): 16-wide geometry + 4-wide topo.
-                    geo_g = mesh.geo20[:, :16][elem]
-                    codes_f = mesh.geo20[:, 16:20][elem]
-                normals = geo_g[:, :12].reshape(-1, 4, 3)
-                dplane = geo_g[:, 12:16]
-                codes = jax.lax.bitcast_convert_type(
-                    codes_f, code_int
-                ).astype(jnp.int32)  # [m, 4]
-                nbrs_all = (codes & 0xFFFFFF) - 1
-            else:
-                normals = mesh.face_normals[elem]
-                dplane = mesh.face_d[elem]
-                nbrs_all = mesh.tet2tet[elem]  # [m, 4]
+                    normals = mesh.face_normals[elem]
+                    dplane = mesh.face_d[elem]
+                    nbrs_all = mesh.tet2tet[elem]  # [m, 4]
 
-            dirv = dest_a - cur
-            if robust:
-                # Never step back through the face we just entered: a
-                # straight ray cannot re-enter a convex element it exited,
-                # and masking that face breaks the t≈0 two-element cycles
-                # grazing rays otherwise fall into on irregular meshes
-                # (see exit_face).
-                backward = (prev[:, None] >= 0) & (
-                    nbrs_all == prev[:, None]
-                )
-                t_exit, face, has_exit, plane_num = exit_face(
-                    normals, dplane, cur, dirv, exclude=backward,
-                    return_num=True,
-                )
-
-                # Relocation chase for stuck lanes. Near a grazing corner
-                # the rounded min-t exit choice can hop the particle into
-                # an element that does NOT contain the onward ray; the
-                # resulting t=0 ejection cascade can cycle instead of
-                # converging, with the position and the element assignment
-                # macroscopically diverged. After 4 consecutive
-                # zero-progress crossings in a NON-containing element,
-                # switch the lane to a stochastic visibility walk
-                # (chase_face_choice): hop toward the point without moving
-                # or scoring anything until containment is restored, then
-                # resume the normal walk (the stuck counter resets on
-                # containment). The same recovery class the reference's
-                # tracer leaves to "not all particles found" printf
-                # truncation (cpp:765-768) — here it repairs instead of
-                # giving up.
-                sd = -plane_num  # signed distance to own faces; reuse
-                # the exit test's plane numerators, not a second einsum.
-                contained = jnp.max(sd, axis=-1) <= 0.0
-                chase = active & (stuck >= 4) & ~contained
-                chase_face = chase_face_choice(
-                    sd, elem, it, dtype, nbrs_all >= 0
-                )
-                face = jnp.where(chase, chase_face, face)
-                t_exit = jnp.where(chase, 0.0, t_exit)
-                has_exit = has_exit | chase
-            elif debug_checks:
-                t_exit, face, has_exit, plane_num = exit_face(
-                    normals, dplane, cur, dirv, return_num=True
-                )
-                sd = -plane_num
-            else:
-                t_exit, face, has_exit = exit_face(
-                    normals, dplane, cur, dirv
-                )
-
-            # Geometric tolerance → ray-parameter space (normals are unit,
-            # so geometric distance = t × |dirv|), floored at a few ulps.
-            dnorm = jnp.linalg.norm(dirv, axis=-1)
-            tol_eff = jnp.maximum(
-                tolerance / jnp.where(dnorm > 0, dnorm, 1.0), tol_floor
-            ).astype(dtype)
-            reached = jnp.logical_or(
-                t_exit >= 1.0 - tol_eff, jnp.logical_not(has_exit)
-            )
-            t_step = jnp.minimum(t_exit, 1.0)
-            xpoint = cur + t_step[:, None] * dirv
-
-            if debug_checks:
-                from jax.experimental import checkify
-
-                # Walk-consistency analog of the reference's
-                # tracklength device print (cpp:618-629): every active
-                # particle must actually be inside (within tolerance +
-                # rounding of) its claimed parent element — a wrong
-                # parent id, a broken hop, or degenerate geometry shows
-                # up here as an off-element position. Reuses the exit
-                # test's signed distances, so the debug cost is a couple
-                # of reductions. Also guards the tally-free initial search.
-                scale = jnp.max(jnp.abs(cur), axis=-1) + 1.0
-                bound = 10.0 * tolerance + 64.0 * tol_floor * scale
-                checkify.check(
-                    jnp.all(
-                        jnp.where(active, jnp.max(sd, axis=-1), 0.0)
-                        <= bound
-                    ),
-                    "particle position outside its parent element "
-                    "(corrupted walk state or degenerate geometry)",
-                )
-
-            crossed = active & ~reached & has_exit
-            # Genuine boundary crossings only (a lane that reaches its
-            # destination inside the current element crosses nothing, and
-            # relocation-chase hops are bookkeeping, not crossings) —
-            # the convention shared by the telemetry counters and the
-            # recorded intersection points.
-            real_cross = crossed & ~chase if robust else crossed
-            if stats:
-                ncross, nchase = lanes[0], lanes[1]
-                lanes[0] = ncross + real_cross.astype(ncross.dtype)
+            with jax.named_scope("walk.geometry"):
+                dirv = dest_a - cur
                 if robust:
-                    lanes[1] = nchase + chase.astype(nchase.dtype)
-            if record_xpoints is not None:
-                # Non-crossing lanes row-index OOB (dropped); lanes past
-                # K crossings column-index OOB (dropped).
-                xp, kx = record_crossing(xp, kx, xpoint, real_cross)
-                lanes[-2], lanes[-1] = xp, kx
-            if packed:
-                # Topology came along in the geo20 row: select the exit
-                # face's code locally (no second table gather).
-                code = jnp.take_along_axis(
-                    codes, face[:, None], axis=1
-                )[:, 0]
-            nbr = jnp.take_along_axis(nbrs_all, face[:, None], axis=1)[:, 0]
-            next_elem = jnp.where(crossed, nbr, jnp.int32(-1))
+                    # Never step back through the face we just entered: a
+                    # straight ray cannot re-enter a convex element it exited,
+                    # and masking that face breaks the t≈0 two-element cycles
+                    # grazing rays otherwise fall into on irregular meshes
+                    # (see exit_face).
+                    backward = (prev[:, None] >= 0) & (
+                        nbrs_all == prev[:, None]
+                    )
+                    t_exit, face, has_exit, plane_num = exit_face(
+                        normals, dplane, cur, dirv, exclude=backward,
+                        return_num=True,
+                    )
 
-            if debug_checks:
-                from jax.experimental import checkify
+                    # Relocation chase for stuck lanes. Near a grazing corner
+                    # the rounded min-t exit choice can hop the particle into
+                    # an element that does NOT contain the onward ray; the
+                    # resulting t=0 ejection cascade can cycle instead of
+                    # converging, with the position and the element assignment
+                    # macroscopically diverged. After 4 consecutive
+                    # zero-progress crossings in a NON-containing element,
+                    # switch the lane to a stochastic visibility walk
+                    # (chase_face_choice): hop toward the point without moving
+                    # or scoring anything until containment is restored, then
+                    # resume the normal walk (the stuck counter resets on
+                    # containment). The same recovery class the reference's
+                    # tracer leaves to "not all particles found" printf
+                    # truncation (cpp:765-768) — here it repairs instead of
+                    # giving up.
+                    sd = -plane_num  # signed distance to own faces; reuse
+                    # the exit test's plane numerators, not a second einsum.
+                    contained = jnp.max(sd, axis=-1) <= 0.0
+                    chase = active & (stuck >= 4) & ~contained
+                    chase_face = chase_face_choice(
+                        sd, elem, it, dtype, nbrs_all >= 0
+                    )
+                    face = jnp.where(chase, chase_face, face)
+                    t_exit = jnp.where(chase, 0.0, t_exit)
+                    has_exit = has_exit | chase
+                elif debug_checks:
+                    t_exit, face, has_exit, plane_num = exit_face(
+                        normals, dplane, cur, dirv, return_num=True
+                    )
+                    sd = -plane_num
+                else:
+                    t_exit, face, has_exit = exit_face(
+                        normals, dplane, cur, dirv
+                    )
 
-                checkify.check(
-                    jnp.all(jnp.isfinite(jnp.where(active[:, None], xpoint, 0.0))),
-                    "non-finite intersection point in walk",
+                # Geometric tolerance → ray-parameter space (normals are unit,
+                # so geometric distance = t × |dirv|), floored at a few ulps.
+                dnorm = jnp.linalg.norm(dirv, axis=-1)
+                tol_eff = jnp.maximum(
+                    tolerance / jnp.where(dnorm > 0, dnorm, 1.0), tol_floor
+                ).astype(dtype)
+                reached = jnp.logical_or(
+                    t_exit >= 1.0 - tol_eff, jnp.logical_not(has_exit)
                 )
-                checkify.check(
-                    jnp.all((next_elem >= -1) & (next_elem < ntet)),
-                    "element id out of range after hop",
-                )
+                t_step = jnp.minimum(t_exit, 1.0)
+                xpoint = cur + t_step[:, None] * dirv
 
-            # --- tally (skipped on the initial location search) -----------
-            if not initial:
-                seg = t_step * dnorm  # |xpoint - cur|
-                # Chase hops are bookkeeping (zero length): keep them out
-                # of the segment count the benchmarks report.
-                score = active & in_flight_a
-                if robust:
-                    score = score & ~chase
-                contrib = jnp.where(score, seg * weight_a, 0.0).astype(dtype)
-                # Flat (elem, group) key; non-scoring rows get the OOB
-                # sentinel and drop — the functional analog of the
-                # reference's group-bounds device assert (cpp:634-638).
-                key = jnp.where(
-                    score & good_group,
-                    elem * n_groups + group_a,
-                    nbins,
-                )
+                if debug_checks:
+                    from jax.experimental import checkify
+
+                    # Walk-consistency analog of the reference's
+                    # tracklength device print (cpp:618-629): every active
+                    # particle must actually be inside (within tolerance +
+                    # rounding of) its claimed parent element — a wrong
+                    # parent id, a broken hop, or degenerate geometry shows
+                    # up here as an off-element position. Reuses the exit
+                    # test's signed distances, so the debug cost is a couple
+                    # of reductions. Also guards the tally-free initial search.
+                    scale = jnp.max(jnp.abs(cur), axis=-1) + 1.0
+                    bound = 10.0 * tolerance + 64.0 * tol_floor * scale
+                    checkify.check(
+                        jnp.all(
+                            jnp.where(active, jnp.max(sd, axis=-1), 0.0)
+                            <= bound
+                        ),
+                        "particle position outside its parent element "
+                        "(corrupted walk state or degenerate geometry)",
+                    )
+
+                crossed = active & ~reached & has_exit
+                # Genuine boundary crossings only (a lane that reaches its
+                # destination inside the current element crosses nothing, and
+                # relocation-chase hops are bookkeeping, not crossings) —
+                # the convention shared by the telemetry counters and the
+                # recorded intersection points.
+                real_cross = crossed & ~chase if robust else crossed
+                if stats:
+                    ncross, nchase = lanes[0], lanes[1]
+                    lanes[0] = ncross + real_cross.astype(ncross.dtype)
+                    if robust:
+                        lanes[1] = nchase + chase.astype(nchase.dtype)
+                if record_xpoints is not None:
+                    # Non-crossing lanes row-index OOB (dropped); lanes past
+                    # K crossings column-index OOB (dropped).
+                    xp, kx = record_crossing(xp, kx, xpoint, real_cross)
+                    lanes[-2], lanes[-1] = xp, kx
+                if packed:
+                    # Topology came along in the geo20 row: select the exit
+                    # face's code locally (no second table gather).
+                    code = jnp.take_along_axis(
+                        codes, face[:, None], axis=1
+                    )[:, 0]
+                nbr = jnp.take_along_axis(nbrs_all, face[:, None], axis=1)[:, 0]
+                next_elem = jnp.where(crossed, nbr, jnp.int32(-1))
+
                 if debug_checks:
                     from jax.experimental import checkify
 
                     checkify.check(
-                        jnp.all(contrib >= 0)
-                        & jnp.all(jnp.isfinite(contrib)),
-                        "negative or non-finite tally contribution",
+                        jnp.all(jnp.isfinite(jnp.where(active[:, None], xpoint, 0.0))),
+                        "non-finite intersection point in walk",
                     )
-                if not score_squares:
-                    flux = flux.at[key * 2].add(contrib, mode="drop")
-                elif tally_scatter == "interleaved":
-                    # Both tally rows in ONE interleaved scalar scatter:
-                    # c at flat slot 2k, c² at 2k+1.
-                    kk = jnp.concatenate([key * 2, key * 2 + 1])
-                    vv = jnp.concatenate([contrib, contrib * contrib])
-                    flux = flux.at[kk].add(vv, mode="drop")
-                else:
-                    flux = flux.at[key * 2].add(contrib, mode="drop")
-                    flux = flux.at[key * 2 + 1].add(
-                        contrib * contrib, mode="drop"
+                    checkify.check(
+                        jnp.all((next_elem >= -1) & (next_elem < ntet)),
+                        "element id out of range after hop",
                     )
-                nseg = nseg + jnp.sum(score).astype(nseg.dtype)
-                if ledger:
-                    # Per-particle scored track length: one elementwise
-                    # FMA — the walk's own conservation ledger (Σ over
-                    # crossings of the scored segment = |final − origin|
-                    # along the ray; checked under debug_checks,
-                    # surfaced as TraceResult.track_length).
-                    pseg = pseg + jnp.where(score, seg, 0.0).astype(dtype)
 
-            # --- boundary conditions (apply_boundary_condition,
-            # cpp:452-515) -------------------------------------------------
-            domain_exit = crossed & (next_elem == -1)
-            if initial:
-                material_stop = jnp.zeros_like(domain_exit)
-            else:
-                if packed:
-                    # differs bit is only ever set for interior faces, so
-                    # no next_elem >= 0 check is needed.
-                    material_stop = crossed & (((code >> 30) & 1) == 1)
-                    nbr_class = (code >> 24) & 0x3F  # class INDEX
-                else:
-                    nbr_class = mesh.class_id[jnp.maximum(next_elem, 0)]
-                    material_stop = (
-                        crossed
-                        & (next_elem >= 0)
-                        & (nbr_class != mesh.class_id[elem])
+            with jax.named_scope("walk.scatter"):
+                # --- tally (skipped on the initial location search) -----------
+                if not initial:
+                    seg = t_step * dnorm  # |xpoint - cur|
+                    # Chase hops are bookkeeping (zero length): keep them out
+                    # of the segment count the benchmarks report.
+                    score = active & in_flight_a
+                    if robust:
+                        score = score & ~chase
+                    contrib = jnp.where(score, seg * weight_a, 0.0).astype(dtype)
+                    # Flat (elem, group) key; non-scoring rows get the OOB
+                    # sentinel and drop — the functional analog of the
+                    # reference's group-bounds device assert (cpp:634-638).
+                    key = jnp.where(
+                        score & good_group,
+                        elem * n_groups + group_a,
+                        nbins,
                     )
-                # A relocation-chase hop is bookkeeping, not a physical
-                # crossing: it must not trigger a material stop.
+                    if debug_checks:
+                        from jax.experimental import checkify
+
+                        checkify.check(
+                            jnp.all(contrib >= 0)
+                            & jnp.all(jnp.isfinite(contrib)),
+                            "negative or non-finite tally contribution",
+                        )
+                    if not score_squares:
+                        flux = flux.at[key * 2].add(contrib, mode="drop")
+                    elif tally_scatter == "interleaved":
+                        # Both tally rows in ONE interleaved scalar scatter:
+                        # c at flat slot 2k, c² at 2k+1.
+                        kk = jnp.concatenate([key * 2, key * 2 + 1])
+                        vv = jnp.concatenate([contrib, contrib * contrib])
+                        flux = flux.at[kk].add(vv, mode="drop")
+                    else:
+                        flux = flux.at[key * 2].add(contrib, mode="drop")
+                        flux = flux.at[key * 2 + 1].add(
+                            contrib * contrib, mode="drop"
+                        )
+                    nseg = nseg + jnp.sum(score).astype(nseg.dtype)
+                    if ledger:
+                        # Per-particle scored track length: one elementwise
+                        # FMA — the walk's own conservation ledger (Σ over
+                        # crossings of the scored segment = |final − origin|
+                        # along the ray; checked under debug_checks,
+                        # surfaced as TraceResult.track_length).
+                        pseg = pseg + jnp.where(score, seg, 0.0).astype(dtype)
+
+            with jax.named_scope("walk.geometry"):
+                # --- boundary conditions (apply_boundary_condition,
+                # cpp:452-515) -------------------------------------------------
+                domain_exit = crossed & (next_elem == -1)
+                if initial:
+                    material_stop = jnp.zeros_like(domain_exit)
+                else:
+                    if packed:
+                        # differs bit is only ever set for interior faces, so
+                        # no next_elem >= 0 check is needed.
+                        material_stop = crossed & (((code >> 30) & 1) == 1)
+                        nbr_class = (code >> 24) & 0x3F  # class INDEX
+                    else:
+                        nbr_class = mesh.class_id[jnp.maximum(next_elem, 0)]
+                        material_stop = (
+                            crossed
+                            & (next_elem >= 0)
+                            & (nbr_class != mesh.class_id[elem])
+                        )
+                    # A relocation-chase hop is bookkeeping, not a physical
+                    # crossing: it must not trigger a material stop.
+                    if robust:
+                        material_stop = material_stop & ~chase
+                newly_done = (active & reached) | domain_exit | material_stop
+
+                if not initial:
+                    mat = jnp.where(
+                        material_stop,
+                        nbr_class,
+                        jnp.where(
+                            (active & reached) | domain_exit,
+                            jnp.int32(-1),
+                            mat,
+                        ),
+                    )
+
+                # --- hop (move_to_next_element hops even freshly-done
+                # material-stop particles, cpp:440-450) -------------------------
+                hopped = crossed & (next_elem != -1)
                 if robust:
-                    material_stop = material_stop & ~chase
-            newly_done = (active & reached) | domain_exit | material_stop
-
-            if not initial:
-                mat = jnp.where(
-                    material_stop,
-                    nbr_class,
-                    jnp.where(
-                        (active & reached) | domain_exit,
-                        jnp.int32(-1),
-                        mat,
-                    ),
-                )
-
-            # --- hop (move_to_next_element hops even freshly-done
-            # material-stop particles, cpp:440-450) -------------------------
-            hopped = crossed & (next_elem != -1)
-            if robust:
-                # The entry-face mask rests on ray convexity, which only
-                # holds for REAL crossings: a chase hop must clear prev,
-                # not set it, or it could mask the ray's true exit from
-                # the new element.
-                prev = jnp.where(
-                    hopped, jnp.where(chase, jnp.int32(-1), elem), prev
-                )
-            elem = jnp.where(hopped, next_elem, elem)
-            cur = jnp.where(active[:, None], xpoint, cur)
-            if robust:
-                # Degeneracy bump (escalated_bump): crack/edge t≈0 cycles
-                # the entry-face mask cannot break are escaped by
-                # guaranteed forward progress per crossing.
-                continuing = crossed & ~newly_done
-                extra, stuck = escalated_bump(
-                    stuck, contained, continuing, t_step, tol_floor,
-                    tol_eff, cur, dnorm, dtype,
-                )
-                cur = jnp.where(
-                    continuing[:, None], cur + extra[:, None] * dirv, cur
-                )
-            done = done | newly_done
+                    # The entry-face mask rests on ray convexity, which only
+                    # holds for REAL crossings: a chase hop must clear prev,
+                    # not set it, or it could mask the ray's true exit from
+                    # the new element.
+                    prev = jnp.where(
+                        hopped, jnp.where(chase, jnp.int32(-1), elem), prev
+                    )
+                elem = jnp.where(hopped, next_elem, elem)
+                cur = jnp.where(active[:, None], xpoint, cur)
+                if robust:
+                    # Degeneracy bump (escalated_bump): crack/edge t≈0 cycles
+                    # the entry-face mask cannot break are escaped by
+                    # guaranteed forward progress per crossing.
+                    continuing = crossed & ~newly_done
+                    extra, stuck = escalated_bump(
+                        stuck, contained, continuing, t_step, tol_floor,
+                        tol_eff, cur, dnorm, dtype,
+                    )
+                    cur = jnp.where(
+                        continuing[:, None], cur + extra[:, None] * dirv, cur
+                    )
+                done = done | newly_done
             return pack_carry(cur, elem, done, mat, flux, nseg, occ,
                               prev, stuck, pseg, lanes, it + 1)
 
@@ -1111,6 +1143,10 @@ def trace_impl(
         carry = run_phase(full_body, carry, phase1_bound)
     (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg, lanes,
      it) = unpack_carry(carry)
+    # Lane-slots (obs/walk_stats.py "lane_slots"): n per full-width
+    # iteration here, S per iteration of each compaction round below —
+    # from the iteration counts after each loop, as float32.
+    slots = jnp.float32(n) * it.astype(jnp.float32)
 
     def compact_round(state, S, bound, stage_unroll=unroll):
         """One compaction round: gather the first S active lanes, advance
@@ -1130,42 +1166,44 @@ def trace_impl(
         compaction."""
         (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg,
          lanes, it) = unpack_carry(state)
-        active = jnp.logical_not(done)
-        idx, n_active = first_k_active(active, S)
-        valid = jnp.arange(S) < n_active
-        if stats:
-            # Occupancy telemetry: active lanes placed vs slots swept,
-            # accumulated once per compaction round.
-            occ = occ + jnp.stack(
-                [jnp.minimum(n_active, S), jnp.zeros_like(n_active) + S]
-            ).astype(jnp.int32)
-        sub_body = make_body(
-            dest[idx],
-            jnp.ones(S, bool),  # selected lanes are in flight by definition
-            weight[idx],
-            group[idx],
-        )
-        sub_carry = pack_carry(
-            cur[idx], elem[idx], jnp.logical_not(valid), mat[idx],
-            flux, nseg, occ, prev[idx], stuck[idx], pseg[idx],
-            [a[idx] for a in lanes], jnp.int32(0),
-        )
+        with jax.named_scope("walk.compact"):
+            active = jnp.logical_not(done)
+            idx, n_active = first_k_active(active, S)
+            valid = jnp.arange(S) < n_active
+            if stats:
+                # Occupancy telemetry: active lanes placed vs slots swept,
+                # accumulated once per compaction round.
+                occ = occ + jnp.stack(
+                    [jnp.minimum(n_active, S), jnp.zeros_like(n_active) + S]
+                ).astype(jnp.int32)
+            sub_body = make_body(
+                dest[idx],
+                jnp.ones(S, bool),  # selected lanes are in flight
+                weight[idx],
+                group[idx],
+            )
+            sub_carry = pack_carry(
+                cur[idx], elem[idx], jnp.logical_not(valid), mat[idx],
+                flux, nseg, occ, prev[idx], stuck[idx], pseg[idx],
+                [a[idx] for a in lanes], jnp.int32(0),
+            )
         (scur, selem, sdone, smat, flux, nseg, occ, sprev, sstuck,
          spseg, slanes, sit) = unpack_carry(
             run_phase(sub_body, sub_carry, bound, unroll=stage_unroll)
         )
-        idx_sb = jnp.where(valid, idx, n)
-        cur = cur.at[idx_sb].set(scur, mode="drop")
-        elem = elem.at[idx_sb].set(selem, mode="drop")
-        done = done.at[idx_sb].set(sdone, mode="drop")
-        mat = mat.at[idx_sb].set(smat, mode="drop")
-        prev = prev.at[idx_sb].set(sprev, mode="drop")
-        stuck = stuck.at[idx_sb].set(sstuck, mode="drop")
-        pseg = pseg.at[idx_sb].set(spseg, mode="drop")
-        lanes = [
-            a.at[idx_sb].set(s, mode="drop")
-            for a, s in zip(lanes, slanes)
-        ]
+        with jax.named_scope("walk.compact"):
+            idx_sb = jnp.where(valid, idx, n)
+            cur = cur.at[idx_sb].set(scur, mode="drop")
+            elem = elem.at[idx_sb].set(selem, mode="drop")
+            done = done.at[idx_sb].set(sdone, mode="drop")
+            mat = mat.at[idx_sb].set(smat, mode="drop")
+            prev = prev.at[idx_sb].set(sprev, mode="drop")
+            stuck = stuck.at[idx_sb].set(sstuck, mode="drop")
+            pseg = pseg.at[idx_sb].set(spseg, mode="drop")
+            lanes = [
+                a.at[idx_sb].set(s, mode="drop")
+                for a, s in zip(lanes, slanes)
+            ]
         return pack_carry(cur, elem, done, mat, flux, nseg, occ, prev,
                           stuck, pseg, lanes, it + sit)
 
@@ -1175,6 +1213,7 @@ def trace_impl(
         for i, (start, size, *rest) in enumerate(compact_stages):
             S = min(n, max(int(size), 1))
             s_unroll = int(rest[0]) if rest else unroll
+            it_before = state[-1]
             if i + 1 < len(compact_stages):
                 # Intermediate stage: one bounded round; leftovers wait.
                 # Guarded so an all-done batch skips the argsort +
@@ -1207,6 +1246,10 @@ def trace_impl(
                     outer_cond, outer_body, (*state, jnp.int32(0))
                 )
                 state = tuple(state)
+            # Every round of a stage sweeps the stage's S lanes.
+            slots = slots + jnp.float32(S) * (
+                state[-1] - it_before
+            ).astype(jnp.float32)
         (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg,
          lanes, it) = unpack_carry(state)
 
@@ -1259,35 +1302,34 @@ def trace_impl(
         (lanes[-2], lanes[-1]) if record_xpoints is not None
         else (None, None)
     )
-    integ_vec = None
-    if integrity:
-        integ_vec = integrity_vector(
-            in_flight, done, weight, pseg, cur, origin, flux, dtype,
-            initial,
-        )
-    stats_vec = None
-    if stats:
-        stats_vec = walk_stats_vector(
-            lanes[0], lanes[1], done, occ[0], occ[1], nseg, it
-        )
-    conv_vec = conv_out = None
-    if conv_state is not None:
-        # Statistical-convergence fold + summary (obs/convergence.py):
-        # reads the flat flux's even (Σc) entries only, after all
-        # scoring — never writes the accumulator, so the tally output
-        # is bit-identical with or without it.
-        if initial:
-            raise ValueError(
-                "conv_state is a move-loop feature: the initial "
-                "location search scores nothing and must not advance "
-                "the batch cadence"
+    integ_vec = stats_vec = conv_vec = conv_out = None
+    with jax.named_scope("obs.fold"):
+        if integrity:
+            integ_vec = integrity_vector(
+                in_flight, done, weight, pseg, cur, origin, flux, dtype,
+                initial,
             )
-        from ..obs.convergence import fold_and_reduce
+        if stats:
+            stats_vec = walk_stats_vector(
+                lanes[0], lanes[1], done, occ[0], occ[1], nseg, it, slots
+            )
+        if conv_state is not None:
+            # Statistical-convergence fold + summary (obs/convergence.py):
+            # reads the flat flux's even (Σc) entries only, after all
+            # scoring — never writes the accumulator, so the tally output
+            # is bit-identical with or without it.
+            if initial:
+                raise ValueError(
+                    "conv_state is a move-loop feature: the initial "
+                    "location search scores nothing and must not advance "
+                    "the batch cadence"
+                )
+            from ..obs.convergence import fold_and_reduce
 
-        conv_out, conv_vec = fold_and_reduce(
-            flux, *conv_state,
-            batch_moves=batch_moves, rel_err_target=rel_err_target,
-        )
+            conv_out, conv_vec = fold_and_reduce(
+                flux, *conv_state,
+                batch_moves=batch_moves, rel_err_target=rel_err_target,
+            )
     return TraceResult(
         position=cur,
         elem=elem,
@@ -1530,7 +1572,7 @@ def merge_megastep_stats(acc, stats):
     revisit the same lanes and only the final count stands)."""
     from ..obs import IDX
 
-    out = acc + stats
+    out = add_walk_stats(acc, stats)
     return out.at[IDX["max_crossings"]].set(
         jnp.maximum(acc[IDX["max_crossings"]], stats[IDX["max_crossings"]])
     )
@@ -1648,50 +1690,54 @@ def megastep_impl(
         (origin, dest, elem, mat, weight, group, alive, flux, prev_even,
          conv, sacc, iacc, cvec, pacc, nseg) = carry
         m = move0 + k
-        region = mesh.class_id[jnp.clip(elem, 0, mesh.ntet - 1)]
-        sig = sigma_t[jnp.clip(region, 0, nclass - 1)]
-        direction, ell, coll_u, roul_u = sample_move(
-            base_key, m, pid, n, dtype
-        )
-        flight = direction * (ell / jnp.maximum(sig, tiny))[:, None]
-        dest = jnp.where(alive[:, None], origin + flight, origin)
+        with jax.named_scope("source.sample"):
+            region = mesh.class_id[jnp.clip(elem, 0, mesh.ntet - 1)]
+            sig = sigma_t[jnp.clip(region, 0, nclass - 1)]
+            direction, ell, coll_u, roul_u = sample_move(
+                base_key, m, pid, n, dtype
+            )
+            flight = direction * (ell / jnp.maximum(sig, tiny))[:, None]
+            dest = jnp.where(alive[:, None], origin + flight, origin)
         r = trace_impl(
             mesh, origin, dest, elem, alive, weight, group, mat, flux,
             conv_state=conv, **walk_kw,
         )
-        ab = absorb_t[
-            jnp.clip(
-                mesh.class_id[jnp.clip(r.elem, 0, mesh.ntet - 1)],
-                0, nclass - 1,
+        with jax.named_scope("source.physics"):
+            ab = absorb_t[
+                jnp.clip(
+                    mesh.class_id[jnp.clip(r.elem, 0, mesh.ntet - 1)],
+                    0, nclass - 1,
+                )
+            ]
+            weight, group, alive2, phys4 = apply_physics(
+                r.position, dest, r.done, r.material_id, weight, group,
+                alive, ab, coll_u, roul_u,
+                eps_near=eps_near,
+                survival_weight=survival_weight,
+                downscatter=downscatter,
+                n_groups=n_groups,
             )
-        ]
-        weight, group, alive2, phys4 = apply_physics(
-            r.position, dest, r.done, r.material_id, weight, group,
-            alive, ab, coll_u, roul_u,
-            eps_near=eps_near,
-            survival_weight=survival_weight,
-            downscatter=downscatter,
-            n_groups=n_groups,
-        )
         flux = r.flux
         if prev_even is not None:
             from ..core.tally import accumulate_batch_squares
 
-            flux, prev_even = accumulate_batch_squares(flux, prev_even)
-        if sacc is not None:
-            sacc = merge_megastep_stats(sacc, r.stats)
-        if iacc is not None:
-            iacc = merge_megastep_integrity(iacc, r.integrity)
-        if cvec is not None:
-            cvec = r.convergence
-        n_trunc = jnp.sum(alive & ~r.done).astype(dtype)
-        pacc = jnp.concatenate(
-            [
-                pacc[:4] + phys4,
-                jnp.sum(alive2).astype(dtype)[None],
-                pacc[5:6] + n_trunc[None],
-            ]
-        )
+            with jax.named_scope("tally.batch_sd"):
+                flux, prev_even = accumulate_batch_squares(flux, prev_even)
+        with jax.named_scope("obs.fold"):
+            if sacc is not None:
+                sacc = merge_megastep_stats(sacc, r.stats)
+            if iacc is not None:
+                iacc = merge_megastep_integrity(iacc, r.integrity)
+            if cvec is not None:
+                cvec = r.convergence
+            n_trunc = jnp.sum(alive & ~r.done).astype(dtype)
+            pacc = jnp.concatenate(
+                [
+                    pacc[:4] + phys4,
+                    jnp.sum(alive2).astype(dtype)[None],
+                    pacc[5:6] + n_trunc[None],
+                ]
+            )
         return (r.position, dest, r.elem, r.material_id, weight, group,
                 alive2, flux, prev_even, r.conv_state, sacc, iacc, cvec,
                 pacc, nseg + r.n_segments)
@@ -1821,7 +1867,7 @@ def _merge_rewalk(a: TraceResult, b: TraceResult, todo) -> TraceResult:
     stats, ledger) accumulate."""
     stats = None
     if a.stats is not None and b.stats is not None:
-        stats = a.stats + b.stats
+        stats = add_walk_stats(a.stats, b.stats)
         # max_crossings is a max, not a sum; truncated is the FINAL
         # count (b saw every still-unfinished lane as in flight).
         from ..obs import IDX

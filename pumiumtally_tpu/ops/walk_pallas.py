@@ -818,8 +818,11 @@ def trace_pallas_impl(
     stats_vec = None
     if stats:
         zero = nseg * 0
+        # Every crossing sweeps every block; the batch's n lanes count
+        # (not the pad), so both backends count one walk alike.
+        slots = jnp.float32(n) * it.astype(jnp.float32)
         stats_vec = walk_stats_vector(
-            ncross_l, nchase_l, done, zero, zero, nseg, it
+            ncross_l, nchase_l, done, zero, zero, nseg, it, slots
         )
     integ_vec = None
     if integrity:

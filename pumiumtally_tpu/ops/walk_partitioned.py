@@ -83,9 +83,11 @@ from ..parallel.particle_sharding import (
 )
 from .geometry import exit_face
 from .walk import (
+    add_walk_stats,
     chase_face_choice,
     escalated_bump,
     first_k_active,
+    lane_slots_word,
     normalize_compact_stages,
     record_crossing,
     resolve_tally_scatter,
@@ -454,6 +456,9 @@ def _walk_phase(
     # compile the dead full-width while_loop at all.
     if phase1_bound > 0:
         carry = run(full_body, valid, carry, phase1_bound)
+    # Lane-slots (obs/walk_stats.py): cap per full-width iteration, S
+    # per iteration of each compaction round — after each loop, float32.
+    slots = jnp.float32(cap) * carry[-1].astype(jnp.float32)
 
     if compact_stages is not None and phase1_bound < max_crossings:
         def compact_round(state, S, bound, stage_unroll=unroll):
@@ -509,6 +514,7 @@ def _walk_phase(
         for i, (start, size, *rest) in enumerate(compact_stages):
             S = min(cap, max(int(size), 1))
             s_unroll = int(rest[0]) if rest else unroll
+            it_before = carry[-1]
             if i + 1 < len(compact_stages):
                 # Intermediate stage: one bounded round; leftovers wait
                 # for a later stage (the final one mops up).
@@ -545,14 +551,19 @@ def _walk_phase(
                     outer_cond, outer_body, (*carry, jnp.int32(0))
                 )
                 carry = tuple(carry)
+            slots = slots + jnp.float32(S) * (
+                carry[-1] - it_before
+            ).astype(jnp.float32)
 
     # prev/stuck return to the caller's carry; the loop counter comes
-    # back LAST (total body iterations executed across all stages — the
-    # per-round walk-cost term of round_stats). The flux rides the loop
-    # flat — restore the caller's layout.
+    # back next to last (total body iterations executed across all
+    # stages — the per-round walk-cost term of round_stats) and the
+    # lane-slots last. The flux rides the loop flat — restore the
+    # caller's layout.
     out = carry[:-1]
     return (
-        out[:6] + (out[6].reshape(flux_shape),) + out[7:] + (carry[-1],)
+        out[:6] + (out[6].reshape(flux_shape),) + out[7:]
+        + (carry[-1], slots)
     )
 
 
@@ -941,14 +952,15 @@ def make_partitioned_step(
              dropped, occ, ncross, nchase, *xpk) = carry
             (cur, elem, done, target, target_elem, material_id, flux_l,
              nseg, occ, prev, stuck, pseg, ncross, nchase, *xpk,
-             w_iters) = walk_fn(
+             w_iters, w_slots) = walk_fn(
                 tables_l, cur, dest, elem, done, target, target_elem,
                 material_id, weight, group, flux_l, nseg, valid, prev,
                 stuck, pseg, occ, ncross, nchase, *xpk,
             )
             return (cur, dest, elem, done, target, target_elem, material_id,
                     weight, group, pid, valid, prev, stuck, pseg, flux_l,
-                    nseg, dropped, occ, ncross, nchase, *xpk), w_iters
+                    nseg, dropped, occ, ncross, nchase, *xpk), (
+                        w_iters, w_slots)
 
         # Telemetry accumulators (per-chip stats vector): [2] compaction
         # occupancy + per-slot crossing/chase counters. Resident — they
@@ -970,7 +982,7 @@ def make_partitioned_step(
                 + cur[:, :1, None] * 0
             )
             carry = carry + (xp0, vzero * 0)
-        carry, w0_iters = run_walk(carry, walk_first)
+        carry, (w0_iters, w0_slots) = run_walk(carry, walk_first)
 
         def pending_somewhere(carry):
             target, valid = carry[4], carry[10]
@@ -980,30 +992,32 @@ def make_partitioned_step(
         stats0 = jnp.zeros((6, rounds_bound), jnp.int32) + vzero[0] * 0
 
         def round_body(state):
-            carry, r, stats = state
+            carry, r, stats, slots = state
             carry, ex_stats = exchange(carry)
-            carry, w_iters = run_walk(carry, walk_follow)
+            carry, (w_iters, w_slots) = run_walk(carry, walk_follow)
             row = jnp.concatenate(
                 [ex_stats, w_iters.astype(jnp.int32)[None]]
             )
             stats = jax.lax.dynamic_update_slice(
                 stats, row[:, None], (0, r)
             )
-            return carry, r + 1, stats
+            return carry, r + 1, stats, slots + w_slots
 
         def round_cond(state):
-            carry, r, _ = state
+            carry, r = state[:2]
             return jnp.logical_and(r < rounds_bound, pending_somewhere(carry))
 
         if rounds_bound > 0:
-            carry, n_rounds, round_stats = jax.lax.while_loop(
-                round_cond, round_body, (carry, nseg0 * 0, stats0)
+            carry, n_rounds, round_stats, round_slots = jax.lax.while_loop(
+                round_cond, round_body,
+                (carry, nseg0 * 0, stats0, nseg0.astype(jnp.float32)),
             )
         else:
             # max_rounds=0: walk-only step (no migration rounds) — used
             # by the phase profiler; the [6, 0] stats buffer must not
             # reach dynamic_update_slice inside a traced body.
             n_rounds, round_stats = nseg0 * 0, stats0
+            round_slots = nseg0.astype(jnp.float32)
         (cur, dest, elem, done, target, target_elem, material_id,
          weight, group, pid, valid, prev, stuck, pseg, flux_l, nseg,
          dropped, occ, ncross, nchase, *xpk) = carry
@@ -1053,6 +1067,7 @@ def make_partitioned_step(
             occ[1].astype(sd_t),
             nseg,
             (w0_iters + jnp.sum(round_stats[5])).astype(sd_t),
+            lane_slots_word(w0_slots + round_slots, sd_t),
         ])
 
         ivec = None
@@ -1427,7 +1442,7 @@ def make_partitioned_megastep(
             # Per-megastep reductions of the per-chip tails: sums
             # everywhere, max of max_crossings, truncated summed over
             # the fused moves (walk.py merge_megastep_stats semantics).
-            s2 = sacc + res.stats
+            s2 = add_walk_stats(sacc, res.stats)
             sacc = s2.at[:, IDX["max_crossings"]].set(
                 jnp.maximum(
                     sacc[:, IDX["max_crossings"]],

@@ -47,6 +47,36 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+class CallSpans:
+    """A public call's span, with its phases as child spans opened one
+    after another on the calling thread (``open`` closes the phase
+    still open), so a phase may end inside a block the next one
+    outlives. Phases named ``p`` record as ``<facade>.<p>``."""
+
+    def __init__(self, name: str):
+        self._facade = name.split(".", 1)[0]
+        self._call = annotate(name)
+        self._phase = None
+
+    def open(self, phase: str) -> None:
+        self.close()
+        self._phase = annotate(f"{self._facade}.{phase}")
+        self._phase.__enter__()
+
+    def close(self) -> None:
+        if self._phase is not None:
+            self._phase.__exit__(None, None, None)
+            self._phase = None
+
+    def __enter__(self):
+        self._call.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return self._call.__exit__(*exc)
+
+
 def device_memory_stats() -> dict:
     """Per-device memory stats where the backend reports them (bytes in
     use / peak / limit) — the observability hook for HBM-capacity work

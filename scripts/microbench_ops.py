@@ -1,6 +1,6 @@
 """Micro-benchmarks of the walk's primitive ops on real hardware.
 
-Pins down where a while-loop iteration's ~4ms goes (profile_walk.py showed
+Pins down where a while-loop iteration's ~4ms goes (a round-2 ablation showed
 the no-tally walk at 737ms/step ≈ gathers, scatter ~300ms):
   gN       — gather [n] rows from [ntet,4,3] normals table (the status quo:
              one of ~4 separate per-crossing gathers)

@@ -1,6 +1,6 @@
 """Sweep while-loop unroll factor and particle batch size on real hardware.
 
-The walk is dispatch-bound (profile_walk.py: the no-tally walk costs ~4 ms
+The walk is dispatch-bound (a round-2 ablation: the no-tally walk costs ~4 ms
 per while-loop iteration at 131k lanes — far above its bandwidth cost), so
 throughput should rise with both unroll (fewer iterations) and batch size
 (more work per iteration at ~constant dispatch cost).

@@ -186,6 +186,10 @@ def _trace(mesh, origin, dest, elem, in_flight=None, **kw):
 def test_stats_vector_schema_length(small_mesh):
     cen = np.asarray(small_mesh.centroids())
     r = _trace(small_mesh, cen[:1], cen[:1] + 1e-3, np.array([0]))
+    assert WALK_STATS_FIELDS == (
+        "crossings", "max_crossings", "chase_hops", "truncated",
+        "occ_active", "occ_slots", "segments", "loop_iters", "lane_slots",
+    )
     assert r.stats.shape == (WALK_STATS_LEN,)
     assert tuple(IDX[f] for f in WALK_STATS_FIELDS) == tuple(
         range(WALK_STATS_LEN)
@@ -281,6 +285,22 @@ def test_stats_knob_off(small_mesh):
     assert r.stats is None
 
 
+def test_lane_slots_carrier_holds_a_megastep():
+    """The lane-slot word holds 2^20 lanes x 1,000 iterations x 12 moves
+    (past int32) without wrapping, summed through the megastep merge."""
+    from pumiumtally_tpu.ops.walk import add_walk_stats, lane_slots_word
+
+    per_move = jnp.zeros(WALK_STATS_LEN, jnp.int32).at[
+        IDX["lane_slots"]].set(lane_slots_word(jnp.float32(2**20 * 1000),
+                                               jnp.int32))
+    acc = jnp.zeros(WALK_STATS_LEN, jnp.int32)
+    for _ in range(12):
+        acc = add_walk_stats(acc, per_move)
+    assert stats_to_dict(acc)["lane_slots"] == 12 * 2**20 * 1000
+    m = np.stack([np.asarray(acc), np.asarray(per_move)]).astype(np.int64)
+    assert reduce_chip_stats(m)["lane_slots"] == 13 * 2**20 * 1000
+
+
 def test_reduce_chip_stats():
     m = np.zeros((2, WALK_STATS_LEN), np.int64)
     m[0, IDX["crossings"]] = 5
@@ -340,6 +360,22 @@ def test_pumitally_telemetry_snapshot():
     # Prometheus exposition renders without error and carries the totals.
     text = t.metrics.render_prometheus()
     assert "pumi_segments_total" in text
+
+
+def test_walk_records_count_lanes_and_dispatches():
+    """Every walk record names the lanes it walked and the compiled
+    launches it made: one per move and per initial search, summed into
+    pumi_dispatches_total; the lane-slots cover every loop iteration."""
+    t = _drive_tally(n=16, moves=3)
+    snap = t.telemetry()
+    walks = [r for r in snap["per_move"]
+             if r["kind"] in ("initial_search", "move")]
+    assert [r["dispatches"] for r in walks] == [1, 1, 1, 1]
+    assert [r["lanes"] for r in walks] == [16] * 4
+    assert all(r["lane_slots"] == 16 * r["loop_iters"] > 0 for r in walks)
+    assert snap["totals"]["dispatches"] == 4
+    assert snap["metrics"]["pumi_dispatches_total"]["series"][0][
+        "value"] == 4
 
 
 def test_pumitally_telemetry_jsonl_stream(tmp_path, monkeypatch):

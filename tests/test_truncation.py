@@ -308,3 +308,49 @@ def test_chase_face_choice_excludes_boundary_faces():
         none_interior,
     )
     assert 0 <= int(face[0]) < 4
+
+
+# ===================================================================== #
+# Flight-record surface: dispatches and the truncated lanes named
+# ===================================================================== #
+def _count_dispatches(t):
+    """Wrap the facade's one walk entry point with a counter."""
+    calls = []
+    inner = t._trace
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return inner(*args, **kwargs)
+
+    t._trace = counted
+    return calls
+
+
+def test_dispatches_count_rewalks(mesh):
+    """A move's record counts its compiled walk launches: 1, plus one
+    per escalation re-walk — the launches the facade actually made."""
+    t = _init(PumiTally(mesh, N, TallyConfig(
+        tolerance=1e-6, max_crossings=2, truncation_retries=5)))
+    calls = _count_dispatches(t)
+    t.move_to_next_location(*_inputs(1))
+    rec = [r for r in t.telemetry()["per_move"] if r["kind"] == "move"][-1]
+    assert rec["dispatches"] == len(calls) > 1
+    assert rec["lanes"] == N
+
+
+def test_truncated_lanes_named_in_record_and_warning(mesh):
+    """Lanes still unfinished are named, up to 8, by particle id, parent
+    element and position, in the move record and the warning."""
+    t = _init(PumiTally(mesh, N, TallyConfig(tolerance=1e-6,
+                                             max_crossings=2)))
+    args = _inputs(1)
+    with pytest.warns(RuntimeWarning, match="Truncated lanes") as w:
+        t.move_to_next_location(*args)
+    rec = [r for r in t.telemetry()["per_move"] if r["kind"] == "move"][-1]
+    named = rec["truncated_lanes"]
+    assert 0 < len(named) == min(8, rec["truncated"])
+    pos = args[0].reshape(N, 3)  # written back in place
+    for ln in named:
+        assert t.element_ids[ln["pid"]] == ln["elem"]
+        np.testing.assert_allclose(ln["pos"], pos[ln["pid"]])
+    assert str(named[0]["pid"]) in str(w[0].message)
