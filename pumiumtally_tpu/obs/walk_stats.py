@@ -56,6 +56,13 @@ WALK_STATS_FIELDS = (
     # within one megastep (2^20 lanes x 1,000 iterations x 12 moves is
     # about 1.3e10); exact below 2^24, and above it to one part in 2^24.
     "lane_slots",
+    # Compaction rounds that ran: for the fit ladder (ops/walk.py
+    # "Straggler compaction"), the narrower stages the walk entered and
+    # advanced at least one chunk — its stage exits taken; for an
+    # explicit schedule, its rounds. Summed over a megastep's fused
+    # moves (merge_megastep_stats). 0 in the flat loop, the Pallas
+    # kernel and the partitioned walk.
+    "ladder_rounds",
 )
 
 WALK_STATS_LEN = len(WALK_STATS_FIELDS)

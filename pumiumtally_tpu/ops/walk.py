@@ -118,6 +118,24 @@ lengths called out in SURVEY.md §7 (hard part 1). With
 
 Semantics (and the scored flux) are identical to the flat loop; only the
 lane scheduling changes.
+
+``compact_stages="fit"`` (the facades' default, utils/ladder.py) decides
+stage boundaries from what the walk observes instead of crossing counts
+fixed before it starts. The widths are static — n, n/8, n/64, ... down
+to about 1,024 lanes (``fit_widths``) — and a stage loops until its live
+lanes fit the next width (checked between unrolled chunks, as the flat
+loop checks ``all(done)``); the last loops until every lane is done.
+Every live lane therefore rides every stage: no lane waits, no stage
+repeats, and each stage costs O(its width) to enter (scatter the last
+stage back, ``first_k_active`` over its own done flags composed with its
+index into the batch, gather). The iteration counter runs on through
+the stages, so each lane walks exactly the flat loop's iterations
+(``max_crossings`` included); only the scatter's summation order
+differs. A short walk leaves the full width after a few chunks; a long
+one (the batch-start search) stays wide while most lanes are live.
+The narrower stages unroll at most twice (``fit_stage_unroll``): each
+unrolled copy of the walk body is compiled code, which a TPU holds in
+device memory, and a narrow stage's iterations are cheap.
 """
 from __future__ import annotations
 
@@ -129,6 +147,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.ladder import FIT, fit_stage_unroll, fit_widths
 from .geometry import exit_face
 
 
@@ -253,12 +272,13 @@ def normalize_compact_stages(
 
 
 def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
-                      slots):
-    """Reduce the per-lane telemetry counters to the [9] per-move stats
+                      slots, rounds):
+    """Reduce the per-lane telemetry counters to the [10] per-move stats
     vector (obs/walk_stats.py WALK_STATS_FIELDS order — drift breaks
     tests/test_obs.py). ONE definition shared by the XLA walk body and
     the Pallas kernel path (ops/walk_pallas.py), so the schema cannot
-    fork between backends. ``slots`` is the float32 lane-slot count."""
+    fork between backends. ``slots`` is the float32 lane-slot count,
+    ``rounds`` the compaction rounds that ran."""
     sd_t = nseg.dtype
     return jnp.stack([
         jnp.sum(ncross_l).astype(sd_t),
@@ -270,6 +290,7 @@ def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
         nseg,
         it.astype(sd_t),
         lane_slots_word(slots, sd_t),
+        rounds.astype(sd_t),
     ])
 
 
@@ -409,11 +430,11 @@ class TraceResult(NamedTuple):
       |position − origin| to fp accumulation (asserted under
       debug_checks, the reference's cpp:618-629 consistency print);
       zeros on initial-search traces (nothing is scored).
-    stats: [9] per-move telemetry vector in the field order of
+    stats: [10] per-move telemetry vector in the field order of
       obs/walk_stats.py WALK_STATS_FIELDS — (real crossings, max real
       crossings per particle, chase hops, truncated walks, compaction
       occupancy numerator/denominator, segments, loop iterations,
-      lane-slots as float32 bits) —
+      lane-slots as float32 bits, compaction rounds) —
       computed inside the jitted program so ONE scalar-vector readback
       per move carries the whole flight-recorder record (the facade's
       old per-move host scan of ``done`` goes away). None with
@@ -559,6 +580,9 @@ def trace_impl(
         element ``(start, size, unroll)`` overriding the walk unroll for
         that stage — narrow tail stages are while-iteration-bound, so
         they often want a larger factor than the full-width phase.
+        The string ``"fit"`` selects the fit ladder instead (module
+        docstring): stages left when their live lanes fit the next
+        width, for any walk length.
       unroll: crossings advanced per while-loop iteration. The body is a
         no-op for already-done lanes, so semantics are unchanged; unrolling
         amortizes the per-iteration dispatch overhead of a TPU while_loop
@@ -601,8 +625,8 @@ def trace_impl(
         obs/walk_stats.py schema) into the jitted program: two int32
         per-lane counters (real crossings, chase hops) updated
         elementwise per crossing — the same cost class as the ledger —
-        plus a [2] occupancy accumulator bumped once per compaction
-        round, reduced to one [9] vector at the end. No extra
+        plus a [3] occupancy-and-rounds accumulator bumped once per
+        compaction round, reduced to one [10] vector at the end. No extra
         dispatches, no extra readbacks (the caller fetches the vector
         INSTEAD of scanning ``done`` host-side). False restores the
         exact pre-telemetry carry for A/B cost attribution.
@@ -791,11 +815,11 @@ def trace_impl(
 
     # Carry layout — ONE definition shared by the walk body, the phase
     # runner and the compaction rounds: a fixed head (done stays at
-    # index 2 for the loop conds), an optional [2] compaction-occupancy
-    # accumulator when stats is on, then every per-lane extra in static
-    # order — [ncross, nchase] when stats, [xp, kx] when recording — so
-    # compaction can gather/scatter the extras uniformly, and the
-    # iteration counter last.
+    # index 2 for the loop conds), an optional [3] compaction accumulator
+    # (occupancy, rounds) when stats is on, then every per-lane extra in
+    # static order — [ncross, nchase] when stats, [xp, kx] when
+    # recording — so compaction can gather/scatter the extras uniformly,
+    # and the iteration counter last.
     def unpack_carry(c):
         cur, elem, done, mat, flux, nseg = c[:6]
         rest = c[6:]
@@ -1092,7 +1116,10 @@ def trace_impl(
 
         return body
 
-    def run_phase(body, carry, bound, unroll=unroll):
+    def run_phase(body, carry, bound, unroll=unroll, exit_live=0):
+        """Loop ``body`` in ``unroll``-iteration chunks while the
+        iteration counter is under ``bound`` and more than ``exit_live``
+        lanes are live (0: until every lane is done)."""
         if unroll > 1:
             inner = body
 
@@ -1102,19 +1129,25 @@ def trace_impl(
                 return c
 
         def cond(c):
-            return jnp.logical_and(
-                c[-1] < bound, jnp.logical_not(jnp.all(c[2]))
-            )
+            if exit_live:
+                left = jnp.sum(jnp.logical_not(c[2]), dtype=jnp.int32)
+                more = left > exit_live
+            else:
+                more = jnp.logical_not(jnp.all(c[2]))
+            return jnp.logical_and(c[-1] < bound, more)
 
         return jax.lax.while_loop(cond, body, carry)
 
-    compact_stages = normalize_compact_stages(
-        compact_stages, compact_after, compact_size, n, max(n // 8, 256)
-    )
+    fit = compact_stages == FIT
+    widths = fit_widths(n) if fit else (n,)
+    if not fit:
+        compact_stages = normalize_compact_stages(
+            compact_stages, compact_after, compact_size, n, max(n // 8, 256)
+        )
 
     full_body = make_body(dest, in_flight, weight, group)
     phase1_bound = (
-        max_crossings if compact_stages is None
+        max_crossings if fit or compact_stages is None
         else min(compact_stages[0][0], max_crossings)
     )
     prev0 = elem * 0 - 1  # device-varying -1: no entry face yet
@@ -1124,10 +1157,10 @@ def trace_impl(
     occ0 = None
     if stats:
         # Telemetry lanes (device-varying zeros): per-lane real-crossing
-        # and chase-hop counters, plus the [2] compaction-occupancy
-        # accumulator (active lanes placed, slots swept).
+        # and chase-hop counters, plus the [3] compaction accumulator
+        # (active lanes placed, slots swept, rounds run).
         lanes0 += [elem * 0, elem * 0]
-        occ0 = jnp.stack([nseg0, nseg0]).astype(jnp.int32)
+        occ0 = jnp.stack([nseg0, nseg0, nseg0]).astype(jnp.int32)
     if record_xpoints is not None:
         xp0 = jnp.zeros((n, int(record_xpoints), 3), dtype)
         kx0 = elem * 0  # per-lane zero (device-varying under shard_map)
@@ -1140,80 +1173,126 @@ def trace_impl(
     carry = pack_carry(origin, elem, done0, mat0, flux, nseg0, occ0,
                        prev0, stuck0, pseg0, lanes0, jnp.int32(0))
     if phase1_bound > 0:
-        carry = run_phase(full_body, carry, phase1_bound)
-    (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg, lanes,
-     it) = unpack_carry(carry)
+        carry = run_phase(full_body, carry, phase1_bound,
+                          exit_live=widths[1] if len(widths) > 1 else 0)
     # Lane-slots (obs/walk_stats.py "lane_slots"): n per full-width
     # iteration here, S per iteration of each compaction round below —
     # from the iteration counts after each loop, as float32.
-    slots = jnp.float32(n) * it.astype(jnp.float32)
+    slots = jnp.float32(n) * carry[-1].astype(jnp.float32)
+
+    def split_carry(c):
+        """A carry's per-lane arrays other than ``done``, in one list,
+        then its done flags, flux, segment count, occupancy and
+        iteration counter: the shape a subset gather works on."""
+        (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg, lanes,
+         it) = unpack_carry(c)
+        return ([cur, elem, mat, prev, stuck, pseg, *lanes], done, flux,
+                nseg, occ, it)
+
+    def join_carry(per_lane, done, flux, nseg, occ, it):
+        cur, elem, mat, prev, stuck, pseg, *lanes = per_lane
+        return pack_carry(cur, elem, done, mat, flux, nseg, occ, prev,
+                          stuck, pseg, lanes, it)
+
+    def gather_subset(live, S, per_lane, batch_idx=None):
+        """Gather the first ``S`` lanes flagged in ``live`` for a
+        compaction round or a fit-ladder stage.
+
+        The pick is ``first_k_active`` (cumsum stable partition) instead
+        of argsort — same first-S selection, far cheaper than a 1M-lane
+        sort. ``batch_idx``, an earlier subset's index into the batch,
+        composes the pick, so ``live`` may be that subset's own flags.
+        Slots past the live count gather lane 0 and start done, and
+        their write-back index is n, so ``scatter_subset`` drops their
+        rows. The per-lane extras (xp/kx buffers, crossing/chase
+        counters) ride the same gather and scatter-back; a forced-done
+        lane never records or counts, so the features compose with
+        compaction. Returns the write-back index, the subset's done
+        flags, its live count, its per-lane arrays and its walk body."""
+        pick, n_live = first_k_active(live, S)
+        valid = jnp.arange(S) < n_live
+        if batch_idx is not None:
+            pick = batch_idx[pick]
+        g = jnp.where(valid, pick, 0)
+        body = make_body(dest[g], jnp.ones(S, bool), weight[g], group[g])
+        return (jnp.where(valid, pick, n), jnp.logical_not(valid), n_live,
+                [a[g] for a in per_lane], body)
+
+    def scatter_subset(idx, per_lane, sub):
+        """Write a subset's arrays back into the batch's (see
+        ``gather_subset``)."""
+        return [a.at[idx].set(s, mode="drop") for a, s in zip(per_lane, sub)]
+
+    def round_occ(n_live, S):
+        """Occupancy telemetry of one round: active lanes placed, slots
+        swept, and the round itself."""
+        return jnp.stack([jnp.minimum(n_live, S), jnp.zeros_like(n_live) + S,
+                          jnp.ones_like(n_live)]).astype(jnp.int32)
 
     def compact_round(state, S, bound, stage_unroll=unroll):
         """One compaction round: gather the first S active lanes, advance
-        them up to `bound` crossings, scatter results back.
-
-        The active-lane index is built with `first_k_active` (cumsum
-        stable partition) instead of argsort — same first-S-active
-        selection, far cheaper than a 1M-lane sort. Slots past the number
-        of active lanes gather clamped garbage; they are neutralized by
-        forcing their done flag and dropping their write-back rows.
-
-        When intersection-point recording or walk stats are on, the
-        per-lane extras (xp/kx buffers, crossing/chase counters) ride
-        the same gather/scatter-back (garbage lanes never record or
-        count: their forced done flag keeps real_cross False, and their
-        write-back rows drop), so the features compose with
-        compaction."""
-        (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg,
-         lanes, it) = unpack_carry(state)
+        them up to `bound` crossings, scatter results back."""
+        per_lane, done, flux, nseg, occ, it = split_carry(state)
         with jax.named_scope("walk.compact"):
-            active = jnp.logical_not(done)
-            idx, n_active = first_k_active(active, S)
-            valid = jnp.arange(S) < n_active
+            idx, sdone, n_active, sub, sub_body = gather_subset(
+                jnp.logical_not(done), S, per_lane
+            )
             if stats:
-                # Occupancy telemetry: active lanes placed vs slots swept,
-                # accumulated once per compaction round.
-                occ = occ + jnp.stack(
-                    [jnp.minimum(n_active, S), jnp.zeros_like(n_active) + S]
-                ).astype(jnp.int32)
-            sub_body = make_body(
-                dest[idx],
-                jnp.ones(S, bool),  # selected lanes are in flight
-                weight[idx],
-                group[idx],
-            )
-            sub_carry = pack_carry(
-                cur[idx], elem[idx], jnp.logical_not(valid), mat[idx],
-                flux, nseg, occ, prev[idx], stuck[idx], pseg[idx],
-                [a[idx] for a in lanes], jnp.int32(0),
-            )
-        (scur, selem, sdone, smat, flux, nseg, occ, sprev, sstuck,
-         spseg, slanes, sit) = unpack_carry(
-            run_phase(sub_body, sub_carry, bound, unroll=stage_unroll)
-        )
+                occ = occ + round_occ(n_active, S)
+        sub, sdone, flux, nseg, occ, sit = split_carry(run_phase(
+            sub_body, join_carry(sub, sdone, flux, nseg, occ, jnp.int32(0)),
+            bound, unroll=stage_unroll,
+        ))
         with jax.named_scope("walk.compact"):
-            idx_sb = jnp.where(valid, idx, n)
-            cur = cur.at[idx_sb].set(scur, mode="drop")
-            elem = elem.at[idx_sb].set(selem, mode="drop")
-            done = done.at[idx_sb].set(sdone, mode="drop")
-            mat = mat.at[idx_sb].set(smat, mode="drop")
-            prev = prev.at[idx_sb].set(sprev, mode="drop")
-            stuck = stuck.at[idx_sb].set(sstuck, mode="drop")
-            pseg = pseg.at[idx_sb].set(spseg, mode="drop")
-            lanes = [
-                a.at[idx_sb].set(s, mode="drop")
-                for a, s in zip(lanes, slanes)
-            ]
-        return pack_carry(cur, elem, done, mat, flux, nseg, occ, prev,
-                          stuck, pseg, lanes, it + sit)
+            *per_lane, done = scatter_subset(
+                idx, [*per_lane, done], [*sub, sdone]
+            )
+        return join_carry(per_lane, done, flux, nseg, occ, it + sit)
 
-    if compact_stages is not None and phase1_bound < max_crossings:
-        state = pack_carry(cur, elem, done, mat, flux, nseg, occ, prev,
-                           stuck, pseg, lanes, it)
+    def fit_stages(state, slots):
+        """The fit ladder's narrower stages (module docstring): enter
+        each with the live lanes of the one before, loop it until they
+        fit the next width, and scatter it back before the next gather,
+        so one subset generation is live beside the batch at a time."""
+        per_lane, done, flux, nseg, occ, it = split_carry(state)
+        live, idx = jnp.logical_not(done), None
+        # The flat loop's last iteration: the narrower stages' chunks
+        # divide ``unroll``, so they stop on it as the full width does.
+        last_it = -(-max_crossings // unroll) * unroll
+        for k, W in enumerate(widths[1:], start=1):
+            with jax.named_scope("walk.compact"):
+                if idx is not None:
+                    *per_lane, done = scatter_subset(
+                        idx, [*per_lane, done], [*sub, sdone]
+                    )
+                idx, sdone, n_live, sub, body = gather_subset(
+                    live, W, per_lane, batch_idx=idx
+                )
+            it_before = it
+            sub, sdone, flux, nseg, occ, it = split_carry(run_phase(
+                body, join_carry(sub, sdone, flux, nseg, occ, it),
+                last_it, unroll=fit_stage_unroll(unroll),
+                exit_live=widths[k + 1] if k + 1 < len(widths) else 0,
+            ))
+            live = jnp.logical_not(sdone)
+            ran = it - it_before
+            if stats:
+                # A stage that ran a chunk is a compaction round.
+                occ = occ + jnp.where(ran > 0, round_occ(n_live, W), 0)
+            slots = slots + jnp.float32(W) * ran.astype(jnp.float32)
+        with jax.named_scope("walk.compact"):
+            *per_lane, done = scatter_subset(
+                idx, [*per_lane, done], [*sub, sdone]
+            )
+        return join_carry(per_lane, done, flux, nseg, occ, it), slots
+
+    if len(widths) > 1:
+        carry, slots = fit_stages(carry, slots)
+    elif compact_stages is not None and phase1_bound < max_crossings:
         for i, (start, size, *rest) in enumerate(compact_stages):
             S = min(n, max(int(size), 1))
             s_unroll = int(rest[0]) if rest else unroll
-            it_before = state[-1]
+            it_before = carry[-1]
             if i + 1 < len(compact_stages):
                 # Intermediate stage: one bounded round; leftovers wait.
                 # Guarded so an all-done batch skips the argsort +
@@ -1221,11 +1300,11 @@ def trace_impl(
                 # outer_cond provides).
                 span = min(compact_stages[i + 1][0], max_crossings) - start
                 if span > 0:
-                    state = jax.lax.cond(
-                        jnp.all(state[2]),
+                    carry = jax.lax.cond(
+                        jnp.all(carry[2]),
                         lambda s: s,
                         lambda s: compact_round(s, S, span, s_unroll),
-                        state,
+                        carry,
                     )
             else:
                 # Final stage: loop rounds to completion.
@@ -1242,16 +1321,16 @@ def trace_impl(
                         rounds < max_rounds, jnp.logical_not(jnp.all(done))
                     )
 
-                *state, _ = jax.lax.while_loop(
-                    outer_cond, outer_body, (*state, jnp.int32(0))
+                *carry, _ = jax.lax.while_loop(
+                    outer_cond, outer_body, (*carry, jnp.int32(0))
                 )
-                state = tuple(state)
+                carry = tuple(carry)
             # Every round of a stage sweeps the stage's S lanes.
             slots = slots + jnp.float32(S) * (
-                state[-1] - it_before
+                carry[-1] - it_before
             ).astype(jnp.float32)
-        (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg,
-         lanes, it) = unpack_carry(state)
+    (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg, lanes,
+     it) = unpack_carry(carry)
 
     if debug_checks and not initial and ledger:
         from jax.experimental import checkify
@@ -1311,7 +1390,8 @@ def trace_impl(
             )
         if stats:
             stats_vec = walk_stats_vector(
-                lanes[0], lanes[1], done, occ[0], occ[1], nseg, it, slots
+                lanes[0], lanes[1], done, occ[0], occ[1], nseg, it, slots,
+                occ[2],
             )
         if conv_state is not None:
             # Statistical-convergence fold + summary (obs/convergence.py):

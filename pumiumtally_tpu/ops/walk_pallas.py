@@ -822,7 +822,7 @@ def trace_pallas_impl(
         # (not the pad), so both backends count one walk alike.
         slots = jnp.float32(n) * it.astype(jnp.float32)
         stats_vec = walk_stats_vector(
-            ncross_l, nchase_l, done, zero, zero, nseg, it, slots
+            ncross_l, nchase_l, done, zero, zero, nseg, it, slots, zero
         )
     integ_vec = None
     if integrity:

@@ -171,7 +171,9 @@ class PartitionedTally:
                 "needs every particle to fit one chip"
             )
         # Straggler compaction resolves against the per-chip slot count
-        # (cap), the lane width each walk phase actually sweeps.
+        # (cap), the lane width each walk phase actually sweeps. The
+        # partitioned walk has no fit ladder: under the "fit" default it
+        # gets the single-stage knobs.
         compact = self.config.resolve_compaction(self.cap)
         self._step_kwargs = dict(
             n_groups=self.config.n_groups,
@@ -191,7 +193,7 @@ class PartitionedTally:
             compact_after=compact[0],
             compact_size=compact[1],
             compact_stages=self.config.resolve_compact_stages(
-                self.cap, ntet=mesh.ntet
+                self.cap, ntet=mesh.ntet, fit_ladder=False
             ),
             exchange_size=exchange_size,
             max_rounds=max_rounds,

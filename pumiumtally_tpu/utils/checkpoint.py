@@ -495,6 +495,12 @@ def _validate_meta(meta: dict, tally, expected_kind: str | None) -> None:
 # --------------------------------------------------------------------- #
 # Plain (single-chip) facade payload
 # --------------------------------------------------------------------- #
+def _replanned_ladder(tally) -> bool:
+    """Whether the tally's ladder was replanned from its first move by
+    compact_stages="adaptive": the one schedule a checkpoint carries."""
+    return tally.config.compact_stages == "adaptive" and tally._replanned
+
+
 def _plain_payload(tally) -> tuple[dict, dict]:
     s = tally.state
     meta = {
@@ -514,10 +520,12 @@ def _plain_payload(tally) -> tuple[dict, dict]:
         # run must reuse that ladder, not replan from a later move's
         # stats (different ladder -> different scatter grouping ->
         # ~1e-15 flux drift, breaking the bitwise-resume guarantee).
+        # Only that replanned ladder is stored: every other schedule
+        # (a string such as "fit" included) comes from the config.
         "replanned": bool(tally._replanned),
         "compact_stages_planned": (
             [list(s) for s in tally._compact_stages]
-            if tally._replanned and tally._compact_stages is not None
+            if _replanned_ladder(tally) and tally._compact_stages is not None
             else None
         ),
     }
@@ -590,7 +598,7 @@ def _apply_plain(tally, meta: dict, arrays: dict) -> None:
     if "replanned" in meta:
         tally._replanned = bool(meta["replanned"])
         planned = meta.get("compact_stages_planned")
-        if tally._replanned and planned is not None:
+        if _replanned_ladder(tally) and planned is not None:
             tally._compact_stages = tuple(
                 tuple(int(x) for x in s) for s in planned
             )

@@ -27,6 +27,8 @@ from typing import Any
 
 import jax.numpy as jnp
 
+from .ladder import FIT
+
 
 @dataclasses.dataclass(frozen=True)
 class TallyConfig:
@@ -46,10 +48,23 @@ class TallyConfig:
       compact_after: full-batch crossings before straggler compaction kicks
         in (ops/walk.py module docstring); None disables compaction. The
         facade disables it automatically for small particle counts.
+        Setting it or ``compact_size`` away from the defaults (32, None)
+        selects this single-stage schedule over the ``"fit"`` default
+        (``resolve_compact_stages``); ``compact_stages=None`` selects
+        it at the defaults.
       compact_size: straggler subset lane count (default n_particles // 8).
-      compact_stages: multi-stage compaction schedule
+      compact_stages: ``"fit"`` (the default) for the fit ladder
+        (ops/walk.py "Straggler compaction"): widths n, n/8 (at least
+        256), n/64, ... down to about 1,024 lanes, each stage left as
+        soon as its live lanes fit the next, for the batch-start search,
+        facade moves and megastep moves alike; it yields to
+        ``compact_after`` / ``compact_size`` set away from their
+        defaults, and the partitioned facade keeps its single-stage
+        schedule under it.
+        Otherwise a multi-stage compaction schedule
         ((start_crossing, subset_size[, unroll]), ...) overriding the
-        two knobs above (ops/walk.py docstring), or the string
+        two knobs above (ops/walk.py docstring), None for those knobs
+        alone, or the string
         ``"auto"`` for the slot-planned dense ladder — the best known
         schedule for walks with ~10-20 crossings per move
         (scripts/plan_ladder.py; BENCHMARKS.md "Slot-exact ladder
@@ -341,7 +356,7 @@ class TallyConfig:
     max_crossings: int | None = None
     compact_after: int | None = 32
     compact_size: int | None = None
-    compact_stages: tuple | str | None = None
+    compact_stages: tuple | str | None = FIT
     unroll: int = 8
     migration_period: int = 100
     sort_by_element: bool = False
@@ -625,10 +640,13 @@ class TallyConfig:
         return self.compact_after, min(size, n_particles)
 
     def resolve_compact_stages(
-        self, n_particles: int, ntet: int | None = None
-    ) -> tuple | None:
-        """Clamp a configured stage schedule to the batch size (None when
-        unset — the single-stage knobs apply).
+        self, n_particles: int, ntet: int | None = None, *,
+        fit_ladder: bool = True,
+    ) -> tuple | str | None:
+        """The schedule a facade hands its walk: a clamped stage
+        schedule, ``"fit"``, or None (the single-stage knobs apply,
+        through :meth:`resolve_compaction`). The one place that decides
+        between the fit ladder and the other schedules.
 
         ``"auto"`` selects the dense ladder — the measured-best TPU
         schedule (7.60 Mseg/s vs the 3-stage schedule's 4.84, round-4
@@ -644,9 +662,21 @@ class TallyConfig:
         in the simulator (31.3M vs 34.2M slot-equivalents at bench
         stats) and adapts the whole shape, not just the starts, to the
         mesh; hardware A/B pending (wave-3 row staged), which is why
-        "auto" still means the measured-best dense ladder."""
+        "auto" still means the measured-best dense ladder.
+
+        ``"fit"`` (the default) resolves to the fit ladder, or to None
+        — the single-stage knobs — in two cases: ``fit_ladder=False``,
+        for a walk that has no fit ladder (the partitioned walk), and
+        ``compact_after`` / ``compact_size`` set away from their
+        defaults (32, None), so that a config naming the single-stage
+        knobs keeps their meaning. A config that wants the single-stage
+        schedule at exactly those defaults sets ``compact_stages=None``."""
         if self.compact_stages is None or n_particles < 1024:
             return None
+        if self.compact_stages == FIT:
+            knobs = (self.compact_after, self.compact_size)
+            defaults = (TallyConfig.compact_after, TallyConfig.compact_size)
+            return FIT if fit_ladder and knobs == defaults else None
         if isinstance(self.compact_stages, str):
             density = (
                 (max(ntet, 1) / 998250.0) ** (1.0 / 3.0)
@@ -677,8 +707,8 @@ class TallyConfig:
                 ) or None
             raise ValueError(
                 "unknown compact_stages string "
-                f"{self.compact_stages!r}; expected 'auto', 'plan', "
-                "'adaptive' or an explicit "
+                f"{self.compact_stages!r}; expected 'fit', 'auto', "
+                "'plan', 'adaptive' or an explicit "
                 "((start, size[, unroll]), ...) schedule"
             )
         return tuple(

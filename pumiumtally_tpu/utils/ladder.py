@@ -19,7 +19,13 @@ schedule. This module turns a decay curve into a schedule:
     moderate under-width stages are genuinely cheap: the dense ladder's
     model estimate matched hardware within 1%, BENCHMARKS.md r4 grid);
   * :func:`plan_stages` — beam search over (start, width) sequences
-    under the executional model.
+    under the executional model;
+  * :func:`fit_widths` / :func:`simulate_fit` — the fit ladder
+    (``compact_stages="fit"``, the default): static widths falling by
+    ``FIT_RATIO`` from the batch size, each stage left as soon as its
+    live lanes fit the next one. Every live lane rides every stage, so
+    which lanes a stage picks does not matter and the model is exact on
+    an integer histogram; ``simulate_ladder(..., "fit")`` uses it.
 
 Cost calibration (round-4 hardware fit, scripts/fit_ladder_model.py):
 time ≈ 81 ns/slot + 110 ms/round on the v5e bench config — a round
@@ -33,14 +39,62 @@ lane to the slowest straggler; the reference has no equivalent knob.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 __all__ = [
+    "FIT",
+    "fit_stage_unroll",
+    "fit_widths",
     "survivors",
     "exp_survivors",
+    "simulate_fit",
     "simulate_ladder",
     "plan_stages",
 ]
+
+# The fit ladder: ``compact_stages`` value, width ratio between stages,
+# the narrowest later width, the first narrower stage's floor, and the
+# narrower stages' unroll (ops/walk.py "Straggler compaction").
+# Each stage's loop compiles its own copies of the walk body, one per
+# unrolled iteration, and on a TPU the compiled program's code sits in
+# device memory beside the data: at 2^20 lanes and 998,250 tets an
+# 8-times unrolled narrower stage added about 40 MB of code to the
+# megastep and 24 MB to the search program (PERF.md, the fit ladder).
+# A narrow stage is cheap per iteration, so its unroll buys little.
+# Unrolled twice, ratio 8 walked fastest of 4, 8 and 16 on a v5e and
+# left the peak below the single 131,072-lane stage's.
+FIT = "fit"
+FIT_RATIO = 8
+FIT_FLOOR = 1024
+FIT_FIRST_FLOOR = 256
+FIT_STAGE_UNROLL = 2
+
+
+def fit_widths(n: int) -> tuple:
+    """Lane widths of the fit ladder's stages for an ``n``-lane batch:
+    ``n`` itself, then ``max(n // 8, 256)`` (256 is the single-stage
+    schedule's subset floor, so a batch of 1,024 to 8,191 lanes still
+    leaves its full width), then ``// 8`` again while a width holds at
+    least ``FIT_FLOOR`` lanes. A batch of 256 lanes or fewer gets the
+    full width alone: the flat loop."""
+    widths = [n]
+    nxt = max(n // FIT_RATIO, FIT_FIRST_FLOOR)
+    while nxt < widths[-1]:
+        widths.append(nxt)
+        nxt = nxt // FIT_RATIO
+        if nxt < FIT_FLOOR:
+            break
+    return tuple(widths)
+
+
+def fit_stage_unroll(unroll: int) -> int:
+    """Walk iterations per loop iteration in the fit ladder's narrower
+    stages: ``FIT_STAGE_UNROLL`` or less, and a divisor of the full
+    width's ``unroll``, so the stages share the flat loop's last
+    iteration (``max_crossings`` rounded up to ``unroll``)."""
+    return math.gcd(unroll, FIT_STAGE_UNROLL)
 
 
 def survivors(counts: np.ndarray, kmax: int | None = None) -> np.ndarray:
@@ -76,6 +130,56 @@ def exp_survivors(n: int, mean_crossings: float,
     # iterations = crossings + 1 → survivors(k) = P(crossings >= k) at
     # k-1; P(c >= x) = exp(-x/m) for the exponential family.
     return n * np.exp(-np.maximum(k - 1, 0) / m)
+
+
+def _to_hist(active_or_hist) -> np.ndarray:
+    """A remaining-iteration histogram (index 0 = done) from a survivors
+    curve (monotone non-increasing) or from such a histogram."""
+    a = np.asarray(active_or_hist, float)
+    if len(a) >= 2 and np.all(np.diff(a) <= 1e-9):
+        # survivors curve a[k] = #{iterations > k} → remaining-iteration
+        # histogram hist[r] = #{iterations == r} = a[r-1] - a[r] for
+        # r >= 1, hist[0] = 0, plus a tail bucket a[-1] for lanes
+        # clipped past the curve's end.
+        return np.concatenate([[0.0], -np.diff(a), [a[-1]]])
+    return a.copy()
+
+
+def simulate_fit(
+    active_or_hist: np.ndarray,
+    n: int,
+    *,
+    unroll: int = 8,
+    max_crossings: int,
+) -> tuple[float, int, int]:
+    """Executed (lane-slots, rounds, loop iterations) of the fit ladder.
+
+    Each stage of :func:`fit_widths` runs chunks of every live lane —
+    ``unroll`` iterations at full width, :func:`fit_stage_unroll` in the
+    narrower stages — while more lanes are live than the next width
+    holds (the last stage: while any is) and the walk's iteration count
+    is under ``max_crossings`` rounded up to ``unroll``; a round is a
+    narrower stage that ran a chunk. Exact for an integer histogram of
+    per-lane iteration counts, the expectation for a survivors curve."""
+    hist = _to_hist(active_or_hist)
+    widths = fit_widths(int(n))
+    bound = -(-int(max_crossings) // unroll) * unroll
+    slots, rounds, it = 0.0, 0, 0
+    for k, width in enumerate(widths):
+        exit_live = widths[k + 1] if k + 1 < len(widths) else 0
+        u = unroll if k == 0 else fit_stage_unroll(unroll)
+        ran = 0
+        while it < bound and hist[1:].sum() > exit_live:
+            # One chunk: every live lane advances `u` iterations.
+            hist = np.concatenate(
+                [[hist[:u + 1].sum()], hist[u + 1:],
+                 np.zeros(min(u, len(hist) - 1))]
+            )
+            ran += u
+            it += u
+        slots += width * ran
+        rounds += int(k > 0 and ran > 0)
+    return slots, rounds, it
 
 
 def _chunk(span: int, unroll: int) -> int:
@@ -125,17 +229,22 @@ def simulate_ladder(
     converted internally) or a remaining-iteration histogram. Every
     phase runs in `unroll`-sized chunks. Returns (slots, rounds);
     apply your own per-slot/per-round costs (fit_ladder_model.py's
-    hardware fit, or plan_stages' default)."""
-    a = np.asarray(active_or_hist, float)
-    if len(a) >= 2 and np.all(np.diff(a) <= 1e-9):
-        # survivors curve a[k] = #{iterations > k} → remaining-iteration
-        # histogram hist[r] = #{iterations == r} = a[r-1] - a[r] for
-        # r >= 1, hist[0] = 0, plus a tail bucket a[-1] for lanes
-        # clipped past the curve's end.
-        hist = np.concatenate([[0.0], -np.diff(a), [a[-1]]])
-    else:
-        hist = a.copy()
+    hardware fit, or plan_stages' default). ``stages="fit"`` prices the
+    fit ladder (:func:`simulate_fit`) over ``max_crossings`` (default:
+    past the histogram's end)."""
+    hist = _to_hist(active_or_hist)
     kmax = len(hist) + 2
+    if isinstance(stages, str):
+        if stages != FIT:
+            raise ValueError(f"unknown ladder policy {stages!r}")
+        slots, rounds, _ = simulate_fit(
+            hist, n, unroll=unroll,
+            max_crossings=kmax + unroll if max_crossings is None
+            else max_crossings,
+        )
+        if round_cost is not None:
+            return slots + rounds * round_cost, rounds
+        return slots, rounds
     slots, rounds = 0.0, 0
 
     stages = tuple(stages)

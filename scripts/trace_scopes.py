@@ -263,7 +263,8 @@ def record(out_dir: str, workload: str, seed: int, moves: int,
             alive = r.get("alive", alive)
     with open(os.path.join(out_dir, "records.json"), "w") as f:
         json.dump({"workload": workload, "seed": seed, "lanes": n,
-                   "stages": [list(s) for s in stages],
+                   "stages": (stages if isinstance(stages, str)
+                              else [list(s) for s in stages]),
                    "unroll": tally.config.unroll, "records": records},
                   f, indent=1, default=str)
     result = split(tracing.find(logdir), os.path.join(out_dir, "hlo"))

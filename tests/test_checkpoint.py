@@ -82,6 +82,36 @@ def test_adaptive_replan_state_rides_checkpoints(tmp_path):
     np.testing.assert_array_equal(a.raw_flux, b.raw_flux)
 
 
+@pytest.mark.parametrize("fmt", ["tally.npz", "tally.shards"])
+def test_default_fit_ladder_round_trips(tmp_path, fmt):
+    """The default config at a batch wide enough for the fit ladder: a
+    checkpoint (plain and sharded) and the runner's in-memory snapshot
+    both restore it, and the resumed move matches the uninterrupted one
+    bit for bit."""
+    from pumiumtally_tpu.utils.checkpoint import restore_state, snapshot_state
+
+    ckpt = str(tmp_path / fmt)
+    n = 16384
+    a = _fresh(n)
+    assert a._compact_stages == "fit"
+    _drive(a, 1, seed=1)
+    a.save_checkpoint(ckpt)
+    snap = snapshot_state(a)
+    _drive(a, 1, seed=2)
+    after = np.array(a.raw_flux)
+
+    restore_state(a, snap)
+    assert a._compact_stages == "fit" and a.iter_count == 1
+    _drive(a, 1, seed=2)
+    np.testing.assert_array_equal(a.raw_flux, after)
+
+    b = _fresh(n)
+    b.restore_checkpoint(ckpt)
+    assert b._compact_stages == "fit" and b.iter_count == 1
+    _drive(b, 1, seed=2)
+    np.testing.assert_array_equal(b.raw_flux, after)
+
+
 def test_mesh_mismatch_rejected(tmp_path):
     ckpt = str(tmp_path / "tally.npz")
     a = _fresh()

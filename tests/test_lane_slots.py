@@ -9,7 +9,9 @@ count (a lane that reaches its destination in a clean single-region box
 runs one iteration per crossing plus the one that reaches it): the
 full-width phase runs to the first stage's start, an intermediate stage
 runs one round of its first S active lanes (by index) for at most its
-span, and the final stage loops rounds until every lane is done."""
+span, and the final stage loops rounds until every lane is done. The
+fit ladder's oracle is ``utils/ladder.simulate_fit`` on the histogram of
+the same counts, the model its planner uses."""
 from __future__ import annotations
 
 import numpy as np
@@ -24,6 +26,7 @@ from pumiumtally_tpu.obs.walk_stats import reduce_chip_stats
 from pumiumtally_tpu.ops import staging
 from pumiumtally_tpu.ops.source import SourceParams
 from pumiumtally_tpu.ops.walk import trace, trace_packed
+from pumiumtally_tpu.utils.ladder import fit_widths, simulate_fit
 
 N = 64
 GROUPS = 2
@@ -85,9 +88,19 @@ def replay(iters, stages, max_crossings):
     return slots, total
 
 
+def replay_fit(iters, n, max_crossings, unroll=1):
+    """(lane-slots, loop iterations, ladder rounds) of the fit ladder,
+    from each lane's iteration count (0 for a parked lane)."""
+    slots, rounds, loops = simulate_fit(
+        np.bincount(iters), n, unroll=unroll, max_crossings=max_crossings
+    )
+    return slots, loops, rounds
+
+
 def _iterations(mesh, elem, origin, dest):
+    # One recorded point is enough: the count runs on past the buffer.
     r = trace(*_args(mesh, elem, origin, dest), max_crossings=mesh.ntet,
-              record_xpoints=mesh.ntet, **KW)
+              record_xpoints=1, **KW)
     assert bool(np.all(np.asarray(r.done)))
     return np.asarray(r.n_xpoints) + 1
 
@@ -204,3 +217,173 @@ def test_lane_slots_partitioned_per_chip(box):
         assert d["lane_slots"] == cap * first + s_follow * int(rounds[chip])
     total = reduce_chip_stats(stats)["lane_slots"]
     assert total == sum(stats_to_dict(row)["lane_slots"] for row in stats)
+
+
+@pytest.mark.parametrize("unroll, max_crossings", [(1, None), (4, None),
+                                                   (1, 15)])
+def test_lane_slots_fit_ladder_trace(box, unroll, max_crossings):
+    """The fit ladder's lane-slots, loop iterations and rounds are the
+    replay's exactly — with unrolled chunks, and with walks cut at
+    max_crossings."""
+    n = 1 << 18  # fit_widths: 262144, 32768, 4096
+    elem, origin, dest = _batch(box, n=n, seed=12)
+    iters = _iterations(box, elem, origin, dest)
+    parked = np.random.default_rng(12).random(n) < 0.1
+    iters[parked] = 0
+    mc = box.ntet if max_crossings is None else max_crossings
+    args = list(_args(box, elem, origin, dest))
+    args[4] = jnp.asarray(~parked)
+    kw = dict(KW, unroll=unroll, max_crossings=mc)
+    d = stats_to_dict(trace(*args, compact_stages="fit", **kw).stats)
+    slots, loops, rounds = replay_fit(iters, n, mc, unroll)
+    assert (d["lane_slots"], d["loop_iters"], d["ladder_rounds"]) == (
+        slots, loops, rounds)
+    assert rounds >= 1 and len(fit_widths(n)) == 3
+    assert d["truncated"] == int((iters > loops).sum())
+    args[8] = make_flux(box.ntet, GROUPS, jnp.float64, flat=True)
+    flat = stats_to_dict(trace(*args, **kw).stats)
+    # The narrower stages' chunks divide the full width's, so rounded up
+    # to it the ladder's iterations are the flat loop's.
+    assert -(-loops // unroll) * unroll == flat["loop_iters"]
+    assert flat["ladder_rounds"] == 0
+    assert d["lane_slots"] < flat["lane_slots"]
+
+
+def test_fit_ladder_small_batch_schedule(box):
+    """A batch of 1,024 to 65,535 lanes still leaves its full width: one
+    narrower stage of max(n/8, 256) lanes (256 is the single-stage
+    schedule's subset floor), replayed exactly; the default config
+    resolves such a batch to the fit ladder."""
+    assert fit_widths(256) == (256,)
+    assert fit_widths(1024) == (1024, 256)
+    assert fit_widths(4096) == (4096, 512)
+    assert fit_widths(16384) == (16384, 2048)
+    assert fit_widths(1 << 16) == (1 << 16, 8192, 1024)
+    assert fit_widths(1 << 20) == (1 << 20, 131072, 16384, 2048)
+    n = 4096
+    assert TallyConfig().resolve_compact_stages(n) == "fit"
+    elem, origin, dest = _batch(box, n=n, seed=13)
+    iters = _iterations(box, elem, origin, dest)
+    d = stats_to_dict(trace(*_args(box, elem, origin, dest),
+                            compact_stages="fit", max_crossings=box.ntet,
+                            **KW).stats)
+    assert (d["lane_slots"], d["loop_iters"], d["ladder_rounds"]) == (
+        replay_fit(iters, n, box.ntet))
+    assert d["ladder_rounds"] == 1
+    assert d["lane_slots"] < n * d["loop_iters"]
+
+
+def test_ladder_rounds_megastep_sums_fused_moves(box):
+    """Under the default (fit) config, one K=3 megastep reports the sum
+    of its three moves' ladder rounds and lane-slots."""
+    src = SourceParams(sigma_t={0: 2.0}, absorption={0: 0.1}, seed=4)
+    n = 16384  # fit_widths: 16384, 2048
+    recs = {}
+    for k in (1, 3):
+        t = PumiTally(box, n, TallyConfig(
+            dtype=jnp.float64, n_groups=GROUPS, tolerance=1e-8, unroll=1,
+            megastep=k))
+        t.initialize_particle_location(
+            np.random.default_rng(1).uniform(0.1, 0.9, (n, 3)).ravel())
+        t.run_source_moves(3, src)
+        recs[k] = [r for r in t.telemetry()["per_move"]
+                   if r["kind"] == "megastep"]
+    per_move = [r["ladder_rounds"] for r in recs[1]]
+    assert all(r >= 1 for r in per_move)
+    assert recs[3][0]["ladder_rounds"] == sum(per_move)
+    assert recs[3][0]["lane_slots"] == sum(r["lane_slots"] for r in recs[1])
+
+
+# Lane-slots the paths that keep their schedules counted before the fit
+# ladder existed (the same scenarios, run on the tree before it).
+UNCHANGED = {
+    "stages": 824, "compact_after": 864, "flat": 1024, "pallas": 600,
+    "facade_stages": [9600, 6976, 5440, 5248],
+    "facade_compact_after": [10240, 8320, 5248, 4864],
+    "partitioned": [35072, 208256],
+}
+
+
+def _unchanged(case, box):
+    elem, origin, dest = _batch(box)
+    one = dict(stages=dict(compact_stages=STAGES),
+               compact_after=dict(compact_after=2, compact_size=16),
+               flat={})
+    if case in one:
+        return stats_to_dict(trace(*_args(box, elem, origin, dest),
+                                   max_crossings=box.ntet, **one[case],
+                                   **KW).stats)["lane_slots"]
+    if case == "pallas":
+        from pumiumtally_tpu.ops.walk_pallas import trace_pallas_impl
+
+        mesh = build_box(1.0, 1.0, 1.0, 3, 3, 3, dtype=jnp.float32)
+        elem, origin, dest = _batch(mesh, n=40, seed=3)
+        args = list(_args(mesh, elem, origin, dest))
+        args[1:3] = [a.astype(jnp.float32) for a in args[1:3]]
+        args[5] = args[5].astype(jnp.float32)
+        args[8] = make_flux(mesh.ntet, GROUPS, jnp.float32, flat=True)
+        return stats_to_dict(trace_pallas_impl(
+            *args, lane_block=16, **dict(KW, max_crossings=mesh.ntet),
+        ).stats)["lane_slots"]
+    if case == "partitioned":
+        from pumiumtally_tpu.parallel.partitioned_api import PartitionedTally
+        from test_mesh_partition import N_DEV
+
+        n = 2048
+        t = PartitionedTally(
+            build_box(1.0, 1.0, 1.0, 4, 4, 4, dtype=jnp.float64), n,
+            TallyConfig(dtype=jnp.float64, n_groups=GROUPS, tolerance=1e-8,
+                        unroll=1), n_parts=N_DEV)
+        rng = np.random.default_rng(2)
+        t.initialize_particle_location(
+            rng.uniform(0.1, 0.9, (n, 3)).ravel())
+        t.move_to_next_location(
+            rng.uniform(0.05, 0.95, (n, 3)).ravel(), np.ones(n, np.int8),
+            np.ones(n), np.zeros(n, np.int32), np.full(n, -1, np.int32))
+    else:
+        kw = (dict(compact_stages=((4, 256), (8, 64)))
+              if case == "facade_stages"
+              else dict(compact_after=4, compact_size=128))
+        t = PumiTally(box, 1024, TallyConfig(
+            dtype=jnp.float64, n_groups=GROUPS, tolerance=1e-8, unroll=1,
+            **kw))
+        t.initialize_particle_location(
+            np.random.default_rng(1).uniform(0.1, 0.9, (1024, 3)).ravel())
+        t.run_source_moves(
+            3, SourceParams(sigma_t={0: 2.0}, absorption={0: 0.1}, seed=4))
+    return [r["lane_slots"] for r in t.telemetry()["per_move"]
+            if "lane_slots" in r]
+
+
+@pytest.mark.parametrize("case", sorted(UNCHANGED))
+def test_lane_slots_unchanged_schedules(box, case):
+    """Explicit schedules (a stage tuple, compact_after/compact_size, on
+    the walk and through the facade's search and megastep moves), the
+    flat loop, the Pallas kernel and the partitioned facade under the
+    default config count exactly the lane-slots they did before."""
+    assert _unchanged(case, box) == UNCHANGED[case]
+
+
+def test_ladder_rounds_per_move_reader():
+    """The benchmark's ``ladder_rounds_per_move``: rounds over the moves
+    of the window's move and megastep records; nothing where the records
+    carry no such counter (a program without it)."""
+    import importlib.util
+    import os
+    import types
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics",
+        "ladder_rounds_per_move.py")
+    spec = importlib.util.spec_from_file_location("ladder_rounds", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    run = types.SimpleNamespace(records=[
+        {"kind": "initial_search", "ladder_rounds": 5},
+        {"kind": "megastep", "moves": 3, "ladder_rounds": 10},
+        {"kind": "move", "ladder_rounds": 2},
+    ])
+    assert mod.read(run) == 12 / 4
+    run.records = [{"kind": "megastep", "moves": 1, "lane_slots": 9},
+                   {"kind": "initial_search", "lane_slots": 4}]
+    assert mod.read(run) is None
