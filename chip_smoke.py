@@ -448,7 +448,7 @@ def phase_partitioned(mesh, n, n_groups, seed, clock, devices):
     # Per-element sums agree to CONSERVATION_RTOL of themselves, or of
     # the mean element flux. The robust walk leaves each zero-progress
     # bump unscored, up to 32 ulps of the coordinate scale (``hop``,
-    # ops/walk.py escalated_bump: 7.6e-6 in the unit box), and the two
+    # ops/walk.py forward_bump: 7.6e-6 in the unit box), and the two
     # walks meet different degenerate crossings. The floor (2.4e-5 at
     # config 2) holds three such hops in one element; a misplaced
     # segment (~0.08 long) is thousands of times above it. worst_by_*

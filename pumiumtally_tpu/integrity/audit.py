@@ -20,10 +20,12 @@ partitioned facade audits entirely from arrays it already holds
 host-side. Production hot paths with auditing off pay nothing.
 
 The reference walker intentionally skips the production kernel's
-robust-mode recovery (chase, escalated bump): in float64 on meshes the
+robust-mode recovery (chase, forward bump): in float64 on meshes the
 builder accepted, the plain walk with the entry-face mask terminates; a
 lane the reference walker cannot finish within the crossing budget is
-counted ``skipped`` (inconclusive), never a mismatch.
+counted ``skipped`` (inconclusive), never a mismatch. It ends a flight
+by the kernel's own reached test (ops/walk.py ``end_of_flight``, run on
+NumPy).
 """
 from __future__ import annotations
 
@@ -69,6 +71,8 @@ class HostReference:
         exit candidates (with the stranded fallback of
         ops/geometry.exit_face).
         """
+        from ..ops.walk import end_of_flight
+
         cur = np.asarray(origin, np.float64).copy()
         dest = np.asarray(dest, np.float64)
         elem = int(elem)
@@ -96,11 +100,10 @@ class HostReference:
                 face = int(np.argmin(t_all))  # stranded fallback
                 t_exit = float(t_all[face])
             has_exit = np.isfinite(t_exit)
-            tol_eff = max(
-                tolerance / (dnorm if dnorm > 0 else 1.0), tol_floor
+            reached, t_step = end_of_flight(
+                np.float64(t_exit), has_exit, np.float64(dnorm), tolerance,
+                tol_floor, xp=np,
             )
-            reached = (t_exit >= 1.0 - tol_eff) or not has_exit
-            t_step = min(t_exit, 1.0)
             track += t_step * dnorm
             cur = cur + t_step * dirv
             crossed = has_exit and not reached

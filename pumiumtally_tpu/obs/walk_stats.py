@@ -63,6 +63,11 @@ WALK_STATS_FIELDS = (
     # moves (merge_megastep_stats). 0 in the flat loop, the Pallas
     # kernel and the partitioned walk.
     "ladder_rounds",
+    # Degeneracy recovery (ops/walk.py "Degeneracy robustness"):
+    # crossings (relocation-chase hops included) that advanced by the
+    # forward bump and not by their own exit, landings of a bump on the
+    # destination included (``forward_bump``). 0 with robust=False.
+    "bumps",
 )
 
 WALK_STATS_LEN = len(WALK_STATS_FIELDS)

@@ -91,10 +91,10 @@ cost (all elementwise, no extra gathers):
     switches to a stochastic visibility walk toward the point
     (chase_face_choice), scoring and recording nothing, until
     containment is restored;
-  * escalated bump — continuing lanes always advance by >= ~32 ulps,
-    doubling per consecutive zero-progress crossing up to the walk
-    tolerance, so crack/edge t=0 stalls terminate in logarithmically
-    many steps (escalated_bump).
+  * forward bump — continuing lanes always advance by >= ~32 ulps of
+    the coordinate, so crack/edge t=0 stalls end; a bump that would
+    pass the destination lands on it instead (forward_bump, with
+    end_of_flight the one end-of-flight rule of every walk body).
 
 Meshes with genuinely overlapping elements are impossible to walk and
 are rejected at build time (mesh/core.py:_check_not_tangled).
@@ -149,7 +149,6 @@ import numpy as np
 
 from ..utils.ladder import FIT, fit_stage_unroll, fit_widths
 from .geometry import exit_face
-
 
 def first_k_active(active: jax.Array, k: int):
     """Indices of the first ``k`` active lanes, via a cumsum stable
@@ -272,13 +271,14 @@ def normalize_compact_stages(
 
 
 def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
-                      slots, rounds):
-    """Reduce the per-lane telemetry counters to the [10] per-move stats
+                      slots, rounds, bumps):
+    """Reduce the per-lane telemetry counters to the [11] per-move stats
     vector (obs/walk_stats.py WALK_STATS_FIELDS order — drift breaks
     tests/test_obs.py). ONE definition shared by the XLA walk body and
     the Pallas kernel path (ops/walk_pallas.py), so the schema cannot
     fork between backends. ``slots`` is the float32 lane-slot count,
-    ``rounds`` the compaction rounds that ran."""
+    ``rounds`` the compaction rounds that ran, ``bumps`` the forward
+    bumps (``forward_bump``)."""
     sd_t = nseg.dtype
     return jnp.stack([
         jnp.sum(ncross_l).astype(sd_t),
@@ -291,6 +291,7 @@ def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
         it.astype(sd_t),
         lane_slots_word(slots, sd_t),
         rounds.astype(sd_t),
+        bumps.astype(sd_t),
     ])
 
 
@@ -352,50 +353,67 @@ def integrity_vector(
     ])
 
 
-def _exp2i(k, dtype):
-    """2**k as ``dtype`` for small non-negative integer k (the bump's
-    stuck counter, clamped <= 48): assemble the float's exponent bits
-    directly instead of paying a transcendental per lane per crossing."""
-    if dtype == jnp.float32:
-        return jax.lax.bitcast_convert_type(
-            ((k + 127) << 23).astype(jnp.int32), jnp.float32
-        )
-    if dtype == jnp.float64:
-        # f64 meshes only exist under x64, where int64 is available.
-        return jax.lax.bitcast_convert_type(
-            (k.astype(jnp.int64) + 1023) << 52, jnp.float64
-        )
-    return jnp.exp2(k.astype(dtype))
+def bump_length(tol_floor, point, xp=jnp):
+    """The forward bump's geometric length at ``point`` ([..., 3]): 32
+    ulps of the coordinate, 4·tol_floor·(1 + max|point|)."""
+    return 4.0 * tol_floor * (1.0 + xp.max(xp.abs(point), axis=-1))
 
 
-def escalated_bump(stuck, contained, continuing, t_step, tol_floor,
-                   tol_eff, cur, dnorm, dtype):
-    """Doubling forward bump for zero-progress crossings, shared by both
-    walk bodies: a continuing particle advances at least ~32 ulps of the
-    coordinate per crossing, doubling per consecutive zero-progress
-    crossing (capped at the walk tolerance) so crack/edge degeneracies
-    are escaped in logarithmically many steps. The counter resets as
-    soon as the particle is genuinely contained or makes a real step.
-    Returns (extra_t, stuck_next)."""
-    scale1 = 1.0 + jnp.max(jnp.abs(cur), axis=-1)
-    nudge0 = 4.0 * tol_floor * scale1 / jnp.where(dnorm > 0, dnorm, 1.0)
-    nudge_t = jnp.minimum(
-        nudge0 * _exp2i(stuck, dtype),
-        jnp.maximum(tol_eff, nudge0),
+def end_of_flight(t_exit, has_exit, dnorm, tolerance, tol_floor, xp=jnp):
+    """The reached test: ONE definition for every walk body (this
+    module, ops/walk_pallas.py, ops/walk_partitioned.py) and the host
+    audit's float64 mirror (integrity/audit.py, ``xp=numpy``). A lane
+    reaches its destination when no exit face lies before it
+    (``t_exit >= 1 - tol_eff``, or no exit at all: a zero-length ray
+    has none). Returns ``(reached, t_step)``."""
+    tol_eff = xp.maximum(
+        tolerance / xp.where(dnorm > 0, dnorm, 1.0), tol_floor
     )
-    zero_step = continuing & (t_step < nudge0) & ~contained
-    # Reset only on REAL progress; lanes that did not continue this
-    # iteration (done, reached, or frozen for migration) keep their
-    # count — the partitioned exchange reads stuck>=4 to know a lane
-    # froze mid-chase and must not carry an entry-face mask across the
-    # cut (the convexity argument covers real crossings only).
+    reached = (t_exit >= 1.0 - tol_eff) | ~has_exit
+    return reached, xp.minimum(t_exit, 1.0)
+
+
+def forward_bump(stuck, contained, continuing, t_step, tol_floor, cur,
+                 dirv, dest, dnorm):
+    """Forward bump for zero-progress crossings, shared by every walk
+    body: a continuing lane (crossed, hopped, not stopped) advances at
+    least ``bump_length`` (32 ulps of the coordinate, taken at the
+    crossing point ``cur``) along its ray per crossing, so crack/edge
+    t≈0 stalls end after at most |ray| / bump crossings. A bump that
+    would carry the lane past its destination (t > 1) lands it exactly
+    on ``dest`` instead; the next iteration's ray has zero length and
+    the lane reaches (``end_of_flight``) in the element it hopped into.
+    Without that cap a lane a few ulps from a destination on an edge
+    many tets share (every tet there reports an exit at t≈0) is bumped
+    past it, the next ray points back, and it oscillates to
+    ``max_crossings``. The crossing itself, its hop, material stop and
+    domain exit are untouched: only the bump is capped.
+
+    ``stuck`` counts consecutive zero-progress crossings in a
+    non-containing element (the relocation chase starts at 4). It
+    resets as soon as the lane is genuinely contained or makes a real
+    step; lanes that did not continue this iteration (done, reached, or
+    frozen for migration) keep their count — the partitioned exchange
+    reads stuck>=4 to know a lane froze mid-chase and must not carry an
+    entry-face mask across the cut (the convexity argument covers real
+    crossings only). Returns ``(cur_next, stuck_next, bumped)``:
+    ``bumped`` marks the crossings that advanced by the bump, landings
+    on ``dest`` included, and not by their own exit."""
+    nudge0 = bump_length(tol_floor, cur) / jnp.where(dnorm > 0, dnorm, 1.0)
+    bumped = continuing & (t_step < nudge0)
     stuck_next = jnp.where(
-        zero_step,
-        jnp.minimum(stuck + 1, 48),
+        bumped & ~contained,
+        stuck + 1,
         jnp.where(continuing, jnp.int32(0), stuck),
     )
-    extra = jnp.maximum(nudge_t - t_step, 0.0)
-    return extra, stuck_next
+    # int32 columns: Mosaic cannot relayout an i1 vector to a column.
+    landed = (bumped & (nudge0 >= 1.0)).astype(jnp.int32)[:, None] != 0
+    moved = bumped.astype(jnp.int32)[:, None] != 0
+    extra = jnp.where(bumped, nudge0 - t_step, 0.0)
+    cur_next = jnp.where(
+        landed, dest, jnp.where(moved, cur + extra[:, None] * dirv, cur)
+    )
+    return cur_next, stuck_next, bumped
 
 
 class TraceResult(NamedTuple):
@@ -430,11 +448,11 @@ class TraceResult(NamedTuple):
       |position − origin| to fp accumulation (asserted under
       debug_checks, the reference's cpp:618-629 consistency print);
       zeros on initial-search traces (nothing is scored).
-    stats: [10] per-move telemetry vector in the field order of
+    stats: [11] per-move telemetry vector in the field order of
       obs/walk_stats.py WALK_STATS_FIELDS — (real crossings, max real
       crossings per particle, chase hops, truncated walks, compaction
       occupancy numerator/denominator, segments, loop iterations,
-      lane-slots as float32 bits, compaction rounds) —
+      lane-slots as float32 bits, compaction rounds, bumps) —
       computed inside the jitted program so ONE scalar-vector readback
       per move carries the whole flight-recorder record (the facade's
       old per-move host scan of ``done`` goes away). None with
@@ -590,7 +608,7 @@ def trace_impl(
         bandwidth-bound) at the price of at most ``unroll - 1`` wasted
         body evaluations at the tail.
       robust: enable the degeneracy-recovery machinery (entry-face mask,
-        relocation chase, escalated bump — module docstring "Degeneracy
+        relocation chase, forward bump — module docstring "Degeneracy
         robustness"). With False the walk has exactly the reference
         tracer's semantics: a lane a numerical degeneracy traps never
         repairs, it just fails to finish within max_crossings and is
@@ -624,9 +642,10 @@ def trace_impl(
       stats: fold the per-move telemetry vector (TraceResult.stats;
         obs/walk_stats.py schema) into the jitted program: two int32
         per-lane counters (real crossings, chase hops) updated
-        elementwise per crossing — the same cost class as the ledger —
-        plus a [3] occupancy-and-rounds accumulator bumped once per
-        compaction round, reduced to one [10] vector at the end. No extra
+        elementwise per crossing — the same cost class as
+        the ledger — plus a [4] accumulator (occupancy and rounds once
+        per compaction round, the bumps per crossing), reduced to one
+        [11] vector at the end. No extra
         dispatches, no extra readbacks (the caller fetches the vector
         INSTEAD of scanning ``done`` host-side). False restores the
         exact pre-telemetry carry for A/B cost attribution.
@@ -815,8 +834,9 @@ def trace_impl(
 
     # Carry layout — ONE definition shared by the walk body, the phase
     # runner and the compaction rounds: a fixed head (done stays at
-    # index 2 for the loop conds), an optional [3] compaction accumulator
-    # (occupancy, rounds) when stats is on, then every per-lane extra in
+    # index 2 for the loop conds), an optional [4] scalar accumulator
+    # when stats is on (compaction: active lanes placed, slots swept,
+    # rounds run; the forward bumps), then every per-lane extra in
     # static order — [ncross, nchase] when stats, [xp, kx] when
     # recording — so compaction can gather/scatter the extras uniformly,
     # and the iteration counter last.
@@ -848,7 +868,6 @@ def trace_impl(
         straggler subsets."""
         # Out-of-range groups map to the OOB key so their rows drop.
         good_group = (group_a >= 0) & (group_a < n_groups)
-
         def body(carry):
             (cur, elem, done, mat, flux, nseg, occ, prev, stuck, pseg,
              lanes, it) = unpack_carry(carry)
@@ -930,15 +949,12 @@ def trace_impl(
                     )
 
                 # Geometric tolerance → ray-parameter space (normals are unit,
-                # so geometric distance = t × |dirv|), floored at a few ulps.
+                # so geometric distance = t × |dirv|), floored at a few ulps;
+                # the shared reached test (end_of_flight).
                 dnorm = jnp.linalg.norm(dirv, axis=-1)
-                tol_eff = jnp.maximum(
-                    tolerance / jnp.where(dnorm > 0, dnorm, 1.0), tol_floor
-                ).astype(dtype)
-                reached = jnp.logical_or(
-                    t_exit >= 1.0 - tol_eff, jnp.logical_not(has_exit)
+                reached, t_step = end_of_flight(
+                    t_exit, has_exit, dnorm, tolerance, tol_floor
                 )
-                t_step = jnp.minimum(t_exit, 1.0)
                 xpoint = cur + t_step[:, None] * dirv
 
                 if debug_checks:
@@ -1099,17 +1115,20 @@ def trace_impl(
                 elem = jnp.where(hopped, next_elem, elem)
                 cur = jnp.where(active[:, None], xpoint, cur)
                 if robust:
-                    # Degeneracy bump (escalated_bump): crack/edge t≈0 cycles
+                    # Degeneracy bump (forward_bump): crack/edge t≈0 cycles
                     # the entry-face mask cannot break are escaped by
-                    # guaranteed forward progress per crossing.
+                    # guaranteed forward progress per crossing, landing on
+                    # the destination where it would pass it.
                     continuing = crossed & ~newly_done
-                    extra, stuck = escalated_bump(
+                    cur, stuck, bumped = forward_bump(
                         stuck, contained, continuing, t_step, tol_floor,
-                        tol_eff, cur, dnorm, dtype,
+                        cur, dirv, dest_a, dnorm,
                     )
-                    cur = jnp.where(
-                        continuing[:, None], cur + extra[:, None] * dirv, cur
-                    )
+                    if stats:
+                        # The bumps sum into occ's last word.
+                        occ = occ + jnp.concatenate([
+                            jnp.zeros(3, jnp.int32),
+                            jnp.sum(bumped, dtype=jnp.int32)[None]])
                 done = done | newly_done
             return pack_carry(cur, elem, done, mat, flux, nseg, occ,
                               prev, stuck, pseg, lanes, it + 1)
@@ -1157,10 +1176,10 @@ def trace_impl(
     occ0 = None
     if stats:
         # Telemetry lanes (device-varying zeros): per-lane real-crossing
-        # and chase-hop counters, plus the [3] compaction accumulator
-        # (active lanes placed, slots swept, rounds run).
+        # and chase-hop counters, plus the [4] scalar accumulator
+        # (carry layout above).
         lanes0 += [elem * 0, elem * 0]
-        occ0 = jnp.stack([nseg0, nseg0, nseg0]).astype(jnp.int32)
+        occ0 = jnp.stack([nseg0] * 4).astype(jnp.int32)
     if record_xpoints is not None:
         xp0 = jnp.zeros((n, int(record_xpoints), 3), dtype)
         kx0 = elem * 0  # per-lane zero (device-varying under shard_map)
@@ -1225,9 +1244,10 @@ def trace_impl(
 
     def round_occ(n_live, S):
         """Occupancy telemetry of one round: active lanes placed, slots
-        swept, and the round itself."""
-        return jnp.stack([jnp.minimum(n_live, S), jnp.zeros_like(n_live) + S,
-                          jnp.ones_like(n_live)]).astype(jnp.int32)
+        swept, and the round itself (no recovery counts)."""
+        z = jnp.zeros_like(n_live)
+        return jnp.stack([jnp.minimum(n_live, S), z + S, z + 1, z]
+                         ).astype(jnp.int32)
 
     def compact_round(state, S, bound, stage_unroll=unroll):
         """One compaction round: gather the first S active lanes, advance
@@ -1343,11 +1363,10 @@ def trace_impl(
         # accumulation plus the robust mode's unscored ulp-scale bump
         # hops (one per crossing at worst).
         dist = jnp.linalg.norm(cur - origin, axis=-1)
-        # The robust bump's unscored hop is capped per crossing at
-        # tol_eff·|ray| = max(tolerance, tol_floor·|dest − cur|), and
-        # |dest − cur| ≤ |dest − origin| (movement is toward dest), so
-        # the allowance must carry the RAY length as well as the
-        # coordinate magnitude.
+        # The robust bump's unscored hop is 4·tol_floor·(1 + max|cur|)
+        # per crossing (forward_bump), within the allowance's
+        # coordinate term; the ray term keeps room for the rounding of
+        # positions along long rays.
         raylen = jnp.linalg.norm(dest - origin, axis=-1)
         scale_d = 1.0 + jnp.maximum(
             jnp.linalg.norm(origin, axis=-1), dist
@@ -1391,7 +1410,7 @@ def trace_impl(
         if stats:
             stats_vec = walk_stats_vector(
                 lanes[0], lanes[1], done, occ[0], occ[1], nseg, it, slots,
-                occ[2],
+                occ[2], occ[3],
             )
         if conv_state is not None:
             # Statistical-convergence fold + summary (obs/convergence.py):

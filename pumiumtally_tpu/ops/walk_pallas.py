@@ -34,8 +34,8 @@ The parity suites compare this kernel BIT-for-BIT against the XLA path
 (tests/test_kernel_pallas.py), which constrains the design:
 
   * the per-lane walk arithmetic reuses the exact helpers of the XLA
-    body (geometry.exit_face, chase_face_choice, escalated_bump), so
-    per-crossing trajectories are identical;
+    body (geometry.exit_face, chase_face_choice, end_of_flight,
+    forward_bump), so per-crossing trajectories are identical;
   * the one-hot gather is exact (single nonzero per row — any reduction
     order yields the table row bitwise);
   * the outer-product scatter resolves same-(elem, group) collisions by
@@ -86,7 +86,8 @@ from .geometry import exit_face
 from .walk import (
     TraceResult,
     chase_face_choice,
-    escalated_bump,
+    end_of_flight,
+    forward_bump,
     integrity_vector,
     walk_stats_vector,
 )
@@ -362,7 +363,7 @@ def _make_kernel(
         tbl_ref, origin_ref, dest_ref, elem_ref, fly_ref, w_ref, g_ref,
         mat_ref, flux_ref,
         pos_out, elem_out, mat_out, done_out, pseg_out, ncross_out,
-        nchase_out, nseg_out, iters_out, flux_out,
+        nchase_out, nseg_out, nbump_out, iters_out, flux_out,
         prev_ref, stuck_ref,
     ):
         i_lt = jax.lax.broadcasted_iota(
@@ -434,6 +435,7 @@ def _make_kernel(
             ncrossb = row(ncross_out, b)
             nchaseb = row(nchase_out, b)
             nseglb = row(nseg_out, b)
+            nbumpb = row(nbump_out, b)
             flyb = row(fly_ref, b) != 0
             weightb = row(w_ref, b)
             groupb = row(g_ref, b)
@@ -479,13 +481,9 @@ def _make_kernel(
                 )
 
             dnorm = jnp.linalg.norm(dirv, axis=-1)
-            tol_eff = jnp.maximum(
-                tolerance / jnp.where(dnorm > 0, dnorm, 1.0), tol_floor
-            ).astype(dtype)
-            reached = jnp.logical_or(
-                t_exit >= 1.0 - tol_eff, jnp.logical_not(has_exit)
+            reached, t_step = end_of_flight(
+                t_exit, has_exit, dnorm, tolerance, tol_floor
             )
-            t_step = jnp.minimum(t_exit, 1.0)
             xpoint = curb + t_step[:, None] * dirv
 
             crossed = active & ~reached & has_exit
@@ -544,15 +542,11 @@ def _make_kernel(
             curb = jnp.where(_col(active), xpoint, curb)
             if robust:
                 continuing = crossed & ~newly_done
-                extra, stuckb = escalated_bump(
+                curb, stuckb, bumped = forward_bump(
                     stuckb, contained, continuing, t_step, tol_floor,
-                    tol_eff, curb, dnorm, dtype,
+                    curb, dirv, destb, dnorm,
                 )
-                curb = jnp.where(
-                    _col(continuing),
-                    curb + extra[:, None] * dirv,
-                    curb,
-                )
+                nbumpb = nbumpb + bumped.astype(nbumpb.dtype)
             doneb = doneb | newly_done
 
             pos_out[b] = curb.T
@@ -564,6 +558,7 @@ def _make_kernel(
             put(ncross_out, b, ncrossb)
             put(nchase_out, b, nchaseb)
             put(nseg_out, b, nseglb)
+            put(nbump_out, b, nbumpb)
             return it
 
         def all_done():
@@ -599,6 +594,7 @@ def _make_kernel(
         ncross_out[...] = zeros_i
         nchase_out[...] = zeros_i
         nseg_out[...] = zeros_i
+        nbump_out[...] = zeros_i
         # The tile accumulator is seeded from the flux operand so the add
         # chain matches the XLA per-crossing scatter association exactly.
         flux_out[...] = flux_ref[...]
@@ -761,18 +757,19 @@ def trace_pallas_impl(
         row_i32,                                       # real crossings
         row_i32,                                       # chase hops
         row_i32,                                       # scored segments
+        row_i32,                                       # bumps
         jax.ShapeDtypeStruct((1, 128), jnp.int32),     # loop iterations
         jax.ShapeDtypeStruct((2 * n_groups, ntet), dtype),  # flux
     )
-    (pos, elem_o, mat, done, pseg, ncross_l, nchase_l, nseg_l, iters,
-     flux_out) = pl.pallas_call(
+    (pos, elem_o, mat, done, pseg, ncross_l, nchase_l, nseg_l, nbump_l,
+     iters, flux_out) = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((nb, B), jnp.int32),  # prev: entry-face element
             pltpu.VMEM((nb, B), jnp.int32),  # stuck: zero-progress count
         ],
-        input_output_aliases={8: 9},  # flux operand → flux output
+        input_output_aliases={8: 10},  # flux operand → flux output
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=2 * _budget_bytes()
         ),
@@ -792,8 +789,8 @@ def trace_pallas_impl(
     pos = pos.transpose(0, 2, 1).reshape(n_pad, 3)[:n]
     elem_o, mat, pseg = (a.reshape(-1)[:n] for a in (elem_o, mat, pseg))
     done = done.reshape(-1)[:n] != 0
-    ncross_l, nchase_l, nseg_l = (
-        a.reshape(-1)[:n] for a in (ncross_l, nchase_l, nseg_l)
+    ncross_l, nchase_l, nseg_l, nbump_l = (
+        a.reshape(-1)[:n] for a in (ncross_l, nchase_l, nseg_l, nbump_l)
     )
     it = iters[0, 0]
 
@@ -822,7 +819,8 @@ def trace_pallas_impl(
         # (not the pad), so both backends count one walk alike.
         slots = jnp.float32(n) * it.astype(jnp.float32)
         stats_vec = walk_stats_vector(
-            ncross_l, nchase_l, done, zero, zero, nseg, it, slots, zero
+            ncross_l, nchase_l, done, zero, zero, nseg, it, slots, zero,
+            jnp.sum(nbump_l),
         )
     integ_vec = None
     if integrity:

@@ -85,7 +85,8 @@ from .geometry import exit_face
 from .walk import (
     add_walk_stats,
     chase_face_choice,
-    escalated_bump,
+    end_of_flight,
+    forward_bump,
     first_k_active,
     lane_slots_word,
     normalize_compact_stages,
@@ -197,10 +198,11 @@ def _walk_phase(
 
     ``occ``/``ncross``/``nchase`` are the telemetry accumulators of the
     per-chip stats vector (PartitionedTraceResult.stats;
-    obs/walk_stats.py): the [2] compaction-occupancy accumulator plus
-    per-SLOT real-crossing and chase-hop counters. They ride the walk
-    carry and the compaction rounds exactly like ``pseg`` but do NOT
-    migrate in the exchange — they measure work executed on this chip.
+    obs/walk_stats.py): the [3] scalar accumulator (compaction
+    occupancy: active lanes placed, slots swept; the forward bumps) plus per-SLOT real-crossing and chase-hop
+    counters. They ride the walk carry and the compaction rounds
+    exactly like ``pseg`` but do NOT migrate in the exchange — they
+    measure work executed on this chip.
 
     ``prev`` holds the ENC-encoded element the particle last hopped out
     of (local id >= 0, remote code < -1 set by the exchange for
@@ -303,14 +305,9 @@ def _walk_phase(
             # matching ops/walk.py exactly so the partitioned and
             # single-chip walks agree on borderline reached decisions.
             dnorm = jnp.linalg.norm(dirv, axis=-1)
-            tol_eff = jnp.maximum(
-                tolerance / jnp.where(dnorm > 0, dnorm, 1.0),
-                tol_floor,
-            ).astype(dtype)
-            reached = jnp.logical_or(
-                t_exit >= 1.0 - tol_eff, jnp.logical_not(has_exit)
+            reached, t_step = end_of_flight(
+                t_exit, has_exit, dnorm, tolerance, tol_floor
             )
-            t_step = jnp.minimum(t_exit, 1.0)
             xpoint = cur + t_step[:, None] * dirv
 
             crossed = active & ~reached & has_exit
@@ -404,16 +401,18 @@ def _walk_phase(
             elem = jnp.where(local_hop, enc, elem)
             cur = jnp.where(active[:, None], xpoint, cur)
             if robust:
-                # (3) degeneracy bump (escalated_bump, shared with
-                # walk.py): guaranteed forward progress per crossing.
+                # (3) degeneracy bump (forward_bump, shared with
+                # walk.py): guaranteed forward progress per crossing,
+                # landing on the destination where it would pass it.
                 continuing = local_hop & ~newly_done
-                extra, stuck = escalated_bump(
+                cur, stuck, bumped = forward_bump(
                     stuck, contained, continuing, t_step, tol_floor,
-                    tol_eff, cur, dnorm, dtype,
+                    cur, dirv, dest_a, dnorm,
                 )
-                cur = jnp.where(
-                    continuing[:, None], cur + extra[:, None] * dirv, cur
-                )
+                occ = occ + jnp.concatenate([
+                    jnp.zeros(2, jnp.int32),
+                    jnp.sum(bumped, dtype=jnp.int32)[None],
+                ])
             done = done | newly_done
             return (cur, elem, done, target, target_elem, material_id,
                     flux, nseg, occ, prev, stuck, pseg, ncross, nchase,
@@ -471,8 +470,9 @@ def _walk_phase(
             idx, n_active = first_k_active(active, S)
             sub_ok = jnp.arange(S) < n_active
             # Occupancy telemetry: active lanes placed vs slots swept.
+            z = jnp.zeros_like(n_active)
             occ = occ + jnp.stack(
-                [jnp.minimum(n_active, S), jnp.zeros_like(n_active) + S]
+                [jnp.minimum(n_active, S), z + S, z]
             ).astype(jnp.int32)
             sub_body = make_body(
                 dest[idx], weight[idx], group[idx], sub_ok
@@ -962,12 +962,13 @@ def make_partitioned_step(
                     nseg, dropped, occ, ncross, nchase, *xpk), (
                         w_iters, w_slots)
 
-        # Telemetry accumulators (per-chip stats vector): [2] compaction
-        # occupancy + per-slot crossing/chase counters. Resident — they
-        # never ride the exchange payload (they measure THIS chip's
-        # work; an adopted slot keeps counting where its last occupant
-        # left off, which is exactly the per-chip total).
-        occ0 = jnp.stack([vzero[0], vzero[0]]) * 0
+        # Telemetry accumulators (per-chip stats vector): [3] compaction
+        # occupancy and the bumps + per-slot crossing/chase
+        # counters. Resident — they never ride the exchange payload
+        # (they measure THIS chip's work; an adopted slot keeps counting
+        # where its last occupant left off, which is exactly the
+        # per-chip total).
+        occ0 = jnp.stack([vzero[0]] * 3) * 0
         carry = (
             cur, dest, elem, done, target0, vzero * 0,
             material_id, weight, group, pid, valid, target0 + 0, vzero * 0,
@@ -1069,6 +1070,7 @@ def make_partitioned_step(
             (w0_iters + jnp.sum(round_stats[5])).astype(sd_t),
             lane_slots_word(w0_slots + round_slots, sd_t),
             nseg * 0,  # ladder_rounds: counted by the single-chip walk
+            occ[2].astype(sd_t),
         ])
 
         ivec = None

@@ -16,7 +16,11 @@ import jax.numpy as jnp
 from pumiumtally_tpu import PumiTally, TallyConfig, build_box
 from pumiumtally_tpu.mesh.box import build_box_arrays
 from pumiumtally_tpu.mesh.core import TetMesh
-from pumiumtally_tpu.ops.walk import chase_face_choice, escalated_bump
+from pumiumtally_tpu.ops.walk import (
+    bump_length,
+    chase_face_choice,
+    forward_bump,
+)
 from pumiumtally_tpu.parallel.partitioned_api import PartitionedTally
 
 N = 32
@@ -241,7 +245,11 @@ def test_escalated_bump_frozen_lane_contract():
     reads ``stuck>=4`` on the far side to know a lane froze mid-chase
     (walk bodies: ``chase = active & (stuck >= 4) & ~contained``). The
     contract that makes this sound: a NON-continuing (frozen) lane
-    KEEPS its zero-progress counter; only real progress resets it."""
+    KEEPS its zero-progress counter; only real progress resets it. The
+    bump (forward_bump) is one fixed length, 32 ulps of the coordinate,
+    whatever the count: it no longer doubles, and a frozen lane or a
+    real step gets none. The destinations lie far (1 along x), so no
+    bump lands on one."""
     dtype = jnp.float64
     tol_floor = 8 * float(jnp.finfo(dtype).eps)
     n = 5
@@ -251,22 +259,26 @@ def test_escalated_bump_frozen_lane_contract():
     continuing = jnp.array([True, True, False, True, True])
     t_step = jnp.array([0.0, 0.0, 0.0, 0.0, 0.5], dtype)
     cur = jnp.ones((n, 3), dtype)
+    dirv = jnp.zeros((n, 3), dtype).at[:, 0].set(1.0)
     dnorm = jnp.ones(n, dtype)
-    tol_eff = jnp.full(n, 1e-8, dtype)
-    extra, nxt = escalated_bump(
-        stuck, contained, continuing, t_step, tol_floor, tol_eff,
-        cur, dnorm, dtype,
+    moved, nxt, bumped = forward_bump(
+        stuck, contained, continuing, t_step, tol_floor, cur, dirv,
+        cur + dirv, dnorm,
     )
     nxt = np.asarray(nxt)
     assert nxt[0] == 1   # zero-progress increments
     assert nxt[1] == 3
     assert nxt[2] == 5   # FROZEN lane keeps its count across the cut
-    assert nxt[3] == 48  # capped (the _exp2i overflow guard)
+    assert nxt[3] == 49  # no cap: nothing doubles with the count
     assert nxt[4] == 0   # real progress resets
-    extra = np.asarray(extra)
-    assert (extra >= 0).all()
-    # The bump doubles per consecutive zero-progress crossing.
-    assert extra[1] > extra[0]
+    extra = np.asarray(moved)[:, 0] - 1.0
+    floor = float(bump_length(tol_floor, cur)[0])
+    assert floor == 4 * tol_floor * 2.0  # 32 ulps of |cur| = 1
+    np.testing.assert_allclose(extra, [floor, floor, 0, floor, 0],
+                               rtol=1e-3)
+    np.testing.assert_array_equal(
+        np.asarray(bumped), [True, True, False, True, False])
+    np.testing.assert_array_equal(np.asarray(moved)[:, 1:], 1.0)
 
 
 def test_escalated_bump_resets_on_containment():
@@ -278,14 +290,15 @@ def test_escalated_bump_resets_on_containment():
     contained = jnp.array([True, False])
     continuing = jnp.array([True, True])
     t_step = jnp.zeros(n, dtype)
-    _, nxt = escalated_bump(
+    cur = jnp.ones((n, 3), dtype)
+    _, nxt, bumped = forward_bump(
         stuck, contained, continuing, t_step,
-        8 * float(jnp.finfo(dtype).eps),
-        jnp.full(n, 1e-8, dtype), jnp.ones((n, 3), dtype),
-        jnp.ones(n, dtype), dtype,
+        8 * float(jnp.finfo(dtype).eps), cur, cur, 2 * cur,
+        jnp.full(n, np.sqrt(3.0), dtype),
     )
     nxt = np.asarray(nxt)
     assert nxt[0] == 0 and nxt[1] == 7
+    assert np.asarray(bumped).all()  # both advance by the bump
 
 
 def test_chase_face_choice_excludes_boundary_faces():
