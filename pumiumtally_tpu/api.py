@@ -54,6 +54,7 @@ from .obs import (
     stats_to_dict,
 )
 from .ops import staging
+from .ops.seed_grid import build_seed_grid
 from .ops.walk import trace, trace_packed
 from .utils.config import TallyConfig
 from .utils.profiling import CallSpans, annotate
@@ -135,6 +136,10 @@ class PumiTally:
             self.state: ParticleState = seed_at_element_centroid(
                 make_particle_state(self.num_particles, dtype=cfg.dtype), mesh
             )
+            # Where the batch-start search starts (ops/seed_grid.py): a
+            # small device table built once, an argument of the init
+            # program alone.
+            self._seed_grid = build_seed_grid(mesh)
             # Flat device layout: [ntet,n_groups,2] on TPU pads the minor
             # dim 2 → 128 under the (8,128) tile (64× HBM; see make_flux).
             self.flux = make_flux(
@@ -704,11 +709,22 @@ class PumiTally:
     def initialize_particle_location(
         self, init_particle_positions: np.ndarray, size: int | None = None
     ) -> None:
-        """Fly all particles from their current positions (the element-0
-        centroid after construction) to their true source positions to
-        discover parent elements; nothing is tallied
-        (search_initial_elements + search_and_rebuild(initial=True),
-        cpp:360-385, 741-746)."""
+        """Find every particle's parent element at its source position;
+        nothing is tallied (search_initial_elements +
+        search_and_rebuild(initial=True), cpp:360-385, 741-746).
+
+        The reference flies each particle from its current position
+        (the element-0 centroid after construction, else where the last
+        batch left it) to the source point. Here a flying lane's walk
+        starts instead from the seed element of its source point's cell
+        in the tally's seed grid (ops/seed_grid.py), a few elements
+        away. The search scores nothing and only the domain boundary
+        stops it, and a seed is used only where no boundary can stand
+        between it and the source point, so the walk ends as the
+        reference's does: in the element holding the source point, on
+        the point (to an ulp). Lanes whose source point lies outside
+        the mesh's bounding box, or in a cell without a seed, start
+        from their current position, as in the reference."""
         with CallSpans("PumiTally.initialize_particle_location") as spans:
             spans.open("stage")
             self._initialize(init_particle_positions, size, spans)
@@ -777,7 +793,7 @@ class PumiTally:
                             self.mesh, s.origin, s.elem, s.material_id,
                             rec_dev, flux_in, perm_in,
                             weight=s.weight, group=s.group, _packed=True,
-                            **tkw,
+                            seed_grid=self._seed_grid, **tkw,
                         )
                     with annotate("PumiTally.readback"):
                         return out, jax.device_get(out[1])
@@ -832,6 +848,7 @@ class PumiTally:
                             s.group,
                             s.material_id,
                             flux_in,
+                            seed_grid=self._seed_grid,
                             **tkw,
                         )
                     with annotate("PumiTally.readback"):

@@ -68,6 +68,10 @@ WALK_STATS_FIELDS = (
     # forward bump and not by their own exit, landings of a bump on the
     # destination included (``forward_bump``). 0 with robust=False.
     "bumps",
+    # Batch-start search (ops/seed_grid.py): flying lanes whose walk
+    # started from their destination cell's grid seed instead of where
+    # the lane stood. 0 on moves, megasteps and the partitioned walk.
+    "grid_seeded",
 )
 
 WALK_STATS_LEN = len(WALK_STATS_FIELDS)

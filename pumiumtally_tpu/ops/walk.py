@@ -272,13 +272,14 @@ def normalize_compact_stages(
 
 def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
                       slots, rounds, bumps):
-    """Reduce the per-lane telemetry counters to the [11] per-move stats
+    """Reduce the per-lane telemetry counters to the [12] per-move stats
     vector (obs/walk_stats.py WALK_STATS_FIELDS order — drift breaks
     tests/test_obs.py). ONE definition shared by the XLA walk body and
     the Pallas kernel path (ops/walk_pallas.py), so the schema cannot
     fork between backends. ``slots`` is the float32 lane-slot count,
     ``rounds`` the compaction rounds that ran, ``bumps`` the forward
-    bumps (``forward_bump``)."""
+    bumps (``forward_bump``). ``grid_seeded`` is left 0: the search's
+    seeding runs before the walk (``count_seeded``)."""
     sd_t = nseg.dtype
     return jnp.stack([
         jnp.sum(ncross_l).astype(sd_t),
@@ -292,6 +293,7 @@ def walk_stats_vector(ncross_l, nchase_l, done, occ0, occ1, nseg, it,
         lane_slots_word(slots, sd_t),
         rounds.astype(sd_t),
         bumps.astype(sd_t),
+        nseg * 0,
     ])
 
 
@@ -448,11 +450,12 @@ class TraceResult(NamedTuple):
       |position − origin| to fp accumulation (asserted under
       debug_checks, the reference's cpp:618-629 consistency print);
       zeros on initial-search traces (nothing is scored).
-    stats: [11] per-move telemetry vector in the field order of
+    stats: [12] per-move telemetry vector in the field order of
       obs/walk_stats.py WALK_STATS_FIELDS — (real crossings, max real
       crossings per particle, chase hops, truncated walks, compaction
       occupancy numerator/denominator, segments, loop iterations,
-      lane-slots as float32 bits, compaction rounds, bumps) —
+      lane-slots as float32 bits, compaction rounds, bumps, lanes the
+      search started from a grid seed) —
       computed inside the jitted program so ONE scalar-vector readback
       per move carries the whole flight-recorder record (the facade's
       old per-move host scan of ``done`` goes away). None with
@@ -554,6 +557,7 @@ def trace_impl(
     batch_moves: int = 1,
     kernel: str = "xla",
     lane_block: int | None = None,
+    seed_grid=None,
 ) -> TraceResult:
     """Advance all particles from origin to dest through the mesh.
 
@@ -645,7 +649,7 @@ def trace_impl(
         elementwise per crossing — the same cost class as
         the ledger — plus a [4] accumulator (occupancy and rounds once
         per compaction round, the bumps per crossing), reduced to one
-        [11] vector at the end. No extra
+        [12] vector at the end. No extra
         dispatches, no extra readbacks (the caller fetches the vector
         INSTEAD of scanning ``done`` host-side). False restores the
         exact pre-telemetry carry for A/B cost attribution.
@@ -705,7 +709,27 @@ def trace_impl(
         the kernel default (walk_pallas.DEFAULT_LANE_BLOCK).  Ignored
         by the XLA body — the facades only thread it on the Pallas
         path, so the XLA jit cache is not fragmented by a no-op key.
+      seed_grid: the batch-start search's seed table
+        (ops/seed_grid.py; ``initial=True`` only). Each flying lane
+        whose destination's cell holds a seed starts its walk from a
+        point inside the seed, before either backend runs; the stats
+        vector's ``grid_seeded`` counts those lanes. None (every move,
+        and the default): the walk starts at ``origin``.
     """
+    seeded = None
+    if seed_grid is not None:
+        if not initial:
+            raise ValueError(
+                "seed_grid moves the start of the batch-start search "
+                "(initial=True) only: a move's ray starts where its "
+                "particle is"
+            )
+        from .seed_grid import seed_starts
+
+        with jax.named_scope("search.seed"):
+            origin, elem, seeded = seed_starts(
+                seed_grid, origin, elem, dest, in_flight, tolerance
+            )
     if kernel == "pallas":
         # The Mosaic path takes trace_impl's exact contract, so the
         # packed-staging program (trace_packed_impl) composes unchanged:
@@ -713,7 +737,7 @@ def trace_impl(
         # ONE compiled program with one H2D and one D2H per move.
         from .walk_pallas import trace_pallas_impl
 
-        return trace_pallas_impl(
+        return count_seeded(trace_pallas_impl(
             mesh, origin, dest, elem, in_flight, weight, group,
             material_id, flux,
             initial=initial,
@@ -737,7 +761,7 @@ def trace_impl(
             rel_err_target=rel_err_target,
             batch_moves=batch_moves,
             lane_block=lane_block,
-        )
+        ), seeded)
     if kernel != "xla":
         raise ValueError(
             f"kernel must be 'xla' or 'pallas' at trace time: {kernel!r}"
@@ -1429,7 +1453,7 @@ def trace_impl(
                 flux, *conv_state,
                 batch_moves=batch_moves, rel_err_target=rel_err_target,
             )
-    return TraceResult(
+    return count_seeded(TraceResult(
         position=cur,
         elem=elem,
         material_id=material_id,
@@ -1444,6 +1468,20 @@ def trace_impl(
         integrity=integ_vec,
         convergence=conv_vec,
         conv_state=conv_out,
+    ), seeded)
+
+
+def count_seeded(result, seeded):
+    """Write the seeded-lane count of the batch-start search
+    (``seed_grid``) into the stats vector's ``grid_seeded`` word; the
+    walk bodies leave it 0."""
+    if seeded is None or result.stats is None:
+        return result
+    from ..obs.walk_stats import IDX
+
+    s = result.stats
+    return result._replace(
+        stats=s.at[IDX["grid_seeded"]].set(seeded.astype(s.dtype))
     )
 
 
@@ -1481,7 +1519,7 @@ def _resolve_auto_kwargs(args, kwargs):
     return kwargs
 
 
-def checked_trace(*args, **kwargs):
+def checked_trace(*args, seed_grid=None, **kwargs):
     """Run the walk with in-kernel invariant checks (OMEGA_H_CHECK parity).
 
     Returns (error, TraceResult); call ``error.throw()`` to raise on the
@@ -1490,7 +1528,9 @@ def checked_trace(*args, **kwargs):
     only the extra per-crossing reductions, not retracing.
     """
     kwargs = _resolve_auto_kwargs(args, kwargs)
-    return _checked_jit(tuple(sorted(kwargs.items())))(*args)
+    return _checked_jit(tuple(sorted(kwargs.items())))(
+        *args, seed_grid=seed_grid
+    )
 
 
 _trace_jit = jax.jit(

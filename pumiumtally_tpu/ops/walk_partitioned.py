@@ -150,7 +150,7 @@ class PartitionedTraceResult(NamedTuple):
     # path order regardless of which chips walked it). None otherwise.
     xpoints: jax.Array | None = None
     n_xpoints: jax.Array | None = None
-    # [n_parts, 10] per-chip telemetry vectors in the
+    # [n_parts, 12] per-chip telemetry vectors in the
     # obs/walk_stats.py WALK_STATS_FIELDS order. The crossing/chase
     # counters are per resident SLOT and do not migrate with particles
     # (they measure work executed on the chip, not particle identity),
@@ -1071,6 +1071,7 @@ def make_partitioned_step(
             lane_slots_word(w0_slots + round_slots, sd_t),
             nseg * 0,  # ladder_rounds: counted by the single-chip walk
             occ[2].astype(sd_t),
+            nseg * 0,  # grid_seeded: the single-chip search's
         ])
 
         ivec = None

@@ -129,7 +129,7 @@ def _families() -> dict:
         "trace_packed": _Family(
             "trace_packed", walk._trace_packed_jit, walk.trace_packed,
             walk.trace_packed_impl, walk._PACKED_FLUX_ARG_INDEX,
-            ("weight", "group", "conv_state"),
+            ("weight", "group", "conv_state", "seed_grid"),
         ),
         "megastep": _Family(
             "megastep", walk._megastep_jit, walk.megastep,

@@ -298,8 +298,10 @@ def test_ladder_rounds_megastep_sums_fused_moves(box):
 # ladder existed (the same scenarios, run on the tree before it).
 UNCHANGED = {
     "stages": 824, "compact_after": 864, "flat": 1024, "pallas": 600,
-    "facade_stages": [9600, 6976, 5440, 5248],
-    "facade_compact_after": [10240, 8320, 5248, 4864],
+    # The first count is the facade's search, started from grid seeds
+    # (ops/seed_grid.py; 9,600 and 10,240 from where the lanes stood).
+    "facade_stages": [7936, 6976, 5440, 5248],
+    "facade_compact_after": [8832, 8320, 5248, 4864],
     "partitioned": [35072, 208256],
 }
 

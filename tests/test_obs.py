@@ -189,7 +189,7 @@ def test_stats_vector_schema_length(small_mesh):
     assert WALK_STATS_FIELDS == (
         "crossings", "max_crossings", "chase_hops", "truncated",
         "occ_active", "occ_slots", "segments", "loop_iters", "lane_slots",
-        "ladder_rounds", "bumps",
+        "ladder_rounds", "bumps", "grid_seeded",
     )
     assert r.stats.shape == (WALK_STATS_LEN,)
     assert tuple(IDX[f] for f in WALK_STATS_FIELDS) == tuple(
